@@ -442,8 +442,10 @@ def main():
     import jax
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import build_model
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if not args.full:
         cfg = cfg.reduced()

@@ -79,7 +79,8 @@ def main():
     v = jnp.asarray(rng.standard_normal((2, s, d)), jnp.bfloat16)
     span = s // args.kv_split
     parts = [dk.decode_partial_pallas(q, k[:, i*span:(i+1)*span],
-                                      v[:, i*span:(i+1)*span], use_hfa=True)
+                                      v[:, i*span:(i+1)*span], use_hfa=True,
+                                      interpret=True)
              for i in range(args.kv_split)]
     om, mm, lm = dk.merge_partials(
         jnp.stack([p[0] for p in parts]), jnp.stack([p[1] for p in parts]),

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 from repro.kernels import bitmath
 
 NEG_INF = -1e30
@@ -79,11 +78,11 @@ def decode_partial_pallas(
     k: jax.Array,
     v: jax.Array,
     *,
+    interpret: bool,
     scale: float | None = None,
     block_kv: int = 128,
     kv_len: int | None = None,
     use_hfa: bool = False,
-    interpret: bool = True,
 ):
     """Partial decode attention.
 
@@ -127,7 +126,7 @@ def decode_partial_pallas(
             pltpu.VMEM((g, LANES), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_decode_partial",

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 NEG_INF = -1e30
 LANES = 128
@@ -88,13 +87,13 @@ def fa2_pallas(
     k: jax.Array,
     v: jax.Array,
     *,
+    interpret: bool,
     causal: bool = False,
     scale: float | None = None,
     block_q: int = 128,
     block_kv: int = 128,
     kv_len: int | None = None,
     q_offset: int | None = None,
-    interpret: bool = True,
     out_dtype=jnp.float32,
     return_lse: bool = False,
 ):
@@ -138,7 +137,7 @@ def fa2_pallas(
             pltpu.VMEM((block_q, LANES), jnp.float32),   # l
             pltpu.VMEM((block_q, d), jnp.float32),       # acc
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="fa2_fwd",

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 
 NEG_INF = -1e30
 LANES = 128
@@ -120,9 +119,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = accv[...].astype(dv_ref.dtype)
 
 
-def fa2_backward(q, k, v, o, do, lse, *, causal=False, scale=None,
-                 block_q=128, block_kv=128, kv_len=None, q_offset=None,
-                 interpret=True):
+def fa2_backward(q, k, v, o, do, lse, *, interpret, causal=False,
+                 scale=None, block_q=128, block_kv=128, kv_len=None,
+                 q_offset=None):
     """Returns (dq, dk, dv) for the padded (bh, lq, d)/(bh, lkv, d) tiles."""
     bh, lq, d = q.shape
     _, lkv, _ = k.shape
@@ -151,7 +150,7 @@ def fa2_backward(q, k, v, o, do, lse, *, causal=False, scale=None,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, iq, ik: (b, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="fa2_bwd_dq",
     )(q, k, v, do, lse3, delta)
@@ -177,7 +176,7 @@ def fa2_backward(q, k, v, o, do, lse, *, causal=False, scale=None,
         ],
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
                         pltpu.VMEM((block_kv, d), jnp.float32)],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret, name="fa2_bwd_dkv",
     )(q, k, v, do, lse3, delta)

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 from repro.kernels import bitmath
 
 NEG_INF = -1e30
@@ -104,13 +103,13 @@ def hfa_pallas(
     k: jax.Array,
     v: jax.Array,
     *,
+    interpret: bool,
     causal: bool = False,
     scale: float | None = None,
     block_q: int = 128,
     block_kv: int = 128,
     kv_len: int | None = None,
     q_offset: int | None = None,
-    interpret: bool = True,
     out_dtype=jnp.float32,
 ) -> jax.Array:
     """Tiled H-FA over (BH, Lq, d) x (BH, Lkv, d) -> (BH, Lq, d)."""
@@ -141,7 +140,7 @@ def hfa_pallas(
             pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="hfa_fwd",

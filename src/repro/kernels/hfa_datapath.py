@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 from repro.core import lns
 from repro.core.numerics import LOG_ZERO
 
@@ -101,10 +100,10 @@ def hfa_datapath_pallas(
     k: jax.Array,
     v: jax.Array,
     *,
+    interpret: bool,
     causal: bool = False,
     scale: float | None = None,
     kv_len: int | None = None,
-    interpret: bool = True,
 ) -> jax.Array:
     """Per-element LNS H-FA over (BH, Lq, d); returns BF16 attention."""
     bh, lq, d = q.shape
@@ -126,7 +125,7 @@ def hfa_datapath_pallas(
         ],
         out_specs=pl.BlockSpec((1, lq, d), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, lq, d), jnp.bfloat16),
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="hfa_datapath",

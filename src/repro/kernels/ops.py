@@ -14,6 +14,13 @@ Implementations:
   hfa_datapath   per-element LNS Pallas kernel (validation only)
 
 On CPU the Pallas kernels run in interpret mode automatically.
+
+The paged serving ops below follow one rule instead: on the TPU every
+paged decode, prefill and verify call runs its Pallas kernel, whatever
+the impl; the impl only picks the numerics (``use_hfa`` for the H-FA
+rail, i.e. any impl starting with "hfa").  Off the TPU they run the jnp
+gather path, unless ``force_pallas`` pins the kernel in interpret mode
+(the parity tests).
 """
 from __future__ import annotations
 
@@ -37,6 +44,12 @@ IMPLS = ("exact", "fa2", "hfa", "fa2_pallas", "hfa_pallas", "hfa_datapath")
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _paged_pallas(force_pallas: bool) -> bool:
+    """Whether a paged op runs its Pallas kernel: always on the TPU,
+    elsewhere only when a parity test pins it (interpret mode)."""
+    return force_pallas or _on_tpu()
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> tuple[jax.Array, int]:
@@ -325,12 +338,15 @@ def _prefill_jnp_grouped(qg, k_cache, v_cache, q_pos, kv_lens, *, scale,
 # ``codec`` / ``k_scales`` / ``v_scales`` select a page codec
 # (:mod:`repro.kernels.page_codec`): the Pallas kernels decode each page
 # tile inside the loop (scales streamed via the same scalar-prefetch
-# index map), and the jnp fallbacks decode the gathered dense view with
-# the *same* codec.decode - codec=None is the raw fp pool, bit-exact to
-# the pre-codec path.
+# index map), and the jnp gather paths decode the gathered dense view
+# with the *same* codec.decode - codec=None is the raw fp pool, bit-exact
+# to the pre-codec path.
+#
+# Pools are head-major, (P, Hkv, page, d) (scale sidecars (P, Hkv, page,
+# 1)); see :mod:`repro.kernels.paged_decode`.
 
 def _gathered_kv(k_pages, v_pages, page_table, codec, k_scales, v_scales):
-    """Dense per-sequence KV view for the jnp fallbacks, codec-decoded."""
+    """Dense per-sequence KV view for the jnp gather path, codec-decoded."""
     k_cache = paged_k.gather_pages(k_pages, page_table)
     v_cache = paged_k.gather_pages(v_pages, page_table)
     if codec is not None:
@@ -345,8 +361,8 @@ def _gathered_kv(k_pages, v_pages, page_table, codec, k_scales, v_scales):
 
 def paged_decode_partials(
     qg: jax.Array,          # (B, Hkv, G, d) grouped queries
-    k_pages: jax.Array,     # (P, page, Hkv, d)
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d)
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32
     kv_lens: jax.Array,     # (B,) int32; 0 marks a free slot
     *,
@@ -360,7 +376,7 @@ def paged_decode_partials(
     """Paged decode partial triplet: (o~ (B,Hkv,G,d), m/l (B,Hkv,G))."""
     b = qg.shape[0]
     use_hfa = impl.startswith("hfa")
-    if force_pallas or (_on_tpu() and impl in ("fa2_pallas", "hfa_pallas")):
+    if _paged_pallas(force_pallas):
         return paged_k.paged_decode_partial_pallas(
             qg, k_pages, v_pages, page_table, kv_lens, scale=scale,
             use_hfa=use_hfa, interpret=not _on_tpu(), codec=codec,
@@ -376,8 +392,8 @@ def paged_decode_partials(
 
 def paged_prefill_partials(
     qg: jax.Array,          # (B, Hkv, G, L, d) grouped chunk queries
-    k_pages: jax.Array,     # (P, page, Hkv, d)
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d)
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32
     start_pos: jax.Array,   # (B,) int32 chunk start position
     kv_lens: jax.Array,     # (B,) int32 valid KV length (start + chunk)
@@ -391,7 +407,7 @@ def paged_prefill_partials(
 ):
     """Paged chunked-prefill partial triplet: shapes (B,Hkv,G,L,[d])."""
     use_hfa = impl.startswith("hfa")
-    if force_pallas or (_on_tpu() and impl in ("fa2_pallas", "hfa_pallas")):
+    if _paged_pallas(force_pallas):
         return paged_pf_k.paged_prefill_partial_pallas(
             qg, k_pages, v_pages, page_table, start_pos, kv_lens,
             scale=scale, use_hfa=use_hfa, interpret=not _on_tpu(),
@@ -408,8 +424,8 @@ def paged_prefill_partials(
 
 def paged_verify_partials(
     qg: jax.Array,          # (B, Hkv, G, K, d) grouped verify queries
-    k_pages: jax.Array,     # (P, page, Hkv, d)
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d)
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32
     seq_lens: jax.Array,    # (B,) int32 pre-step KV length; 0 = free slot
     chunk_lens: jax.Array,  # (B,) int32 real input count this step
@@ -423,7 +439,7 @@ def paged_verify_partials(
 ):
     """Paged speculative-verify partial triplet: shapes (B,Hkv,G,K,[d])."""
     use_hfa = impl.startswith("hfa")
-    if force_pallas or (_on_tpu() and impl in ("fa2_pallas", "hfa_pallas")):
+    if _paged_pallas(force_pallas):
         return paged_v_k.paged_verify_partial_pallas(
             qg, k_pages, v_pages, page_table, seq_lens, chunk_lens,
             scale=scale, use_hfa=use_hfa, interpret=not _on_tpu(),
@@ -441,8 +457,8 @@ def paged_verify_partials(
 
 def paged_prefill_attention(
     q: jax.Array,           # (B, L, H, d) one prefill chunk per sequence
-    k_pages: jax.Array,     # (P, page, Hkv, d) shared block pool
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d) shared block pool
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32
     start_pos: jax.Array,   # (B,) int32 chunk start position
     chunk_lens: jax.Array,  # (B,) int32 real (unpadded) chunk length
@@ -458,15 +474,15 @@ def paged_prefill_attention(
 
     The chunk's K/V must already be scattered into the pools
     (:func:`repro.kernels.paged_prefill.write_chunk_kv`); queries then
-    attend causally to KV positions ``<= start_pos[b] + i``.  On TPU the
-    paged-prefill Pallas kernel walks the page table with scalar
-    prefetch and finalizes with LogDiv for the H-FA impls; elsewhere a
-    jnp path gathers the pages into a dense view (the CPU CI path).
-    ``force_pallas`` pins the kernel (interpret mode off-TPU) for
-    parity tests.
+    attend causally to KV positions ``<= start_pos[b] + i``.  On the TPU
+    the paged-prefill Pallas kernel walks the page table with scalar
+    prefetch, on both rails; the result is finalized with LogDiv on the
+    H-FA rail.  Elsewhere a jnp path gathers the pages into a dense view
+    (the CPU path).  ``force_pallas`` pins the kernel (interpret mode
+    off the TPU) for parity tests.
     """
     b, l, h, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = h // hkv
     use_hfa = impl.startswith("hfa")
     kv_lens = (start_pos + chunk_lens).astype(jnp.int32)
@@ -483,8 +499,8 @@ def paged_prefill_attention(
 
 def paged_decode_attention(
     q: jax.Array,           # (B, 1, H, d) single new token per slot
-    k_pages: jax.Array,     # (P, page, Hkv, d) shared block pool
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d) shared block pool
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32
     kv_lens: jax.Array,     # (B,) int32; 0 marks a free slot
     *,
@@ -497,17 +513,16 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Continuous-batching decode attention against a paged KV cache.
 
-    On TPU the paged Pallas kernel streams pages straight from HBM via
-    the page table (scalar prefetch) and finalizes with LogDiv for the
-    H-FA impls.  Elsewhere (or for non-Pallas impls) a jnp path gathers
-    the sequence's pages into a dense view and reuses the grouped decode
-    math - same numerics, XLA-compiled, which is also what the CPU CI
-    exercises end-to-end.  ``force_pallas`` pins the kernel (interpret
-    mode off-TPU) for parity tests.
+    On the TPU the paged Pallas kernel streams pages straight from HBM
+    via the page table (scalar prefetch), on both rails; the H-FA rail
+    finalizes with LogDiv.  Elsewhere a jnp path gathers the sequence's
+    pages into a dense view and reuses the grouped decode math (the CPU
+    path the test suite exercises end to end).  ``force_pallas`` pins
+    the kernel (interpret mode off the TPU) for parity tests.
     """
     b, one, h, d = q.shape
     assert one == 1
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = h // hkv
     use_hfa = impl.startswith("hfa")
     qg = q.reshape(b, h, d).reshape(b, hkv, g, d)
@@ -521,8 +536,8 @@ def paged_decode_attention(
 
 def paged_verify_attention(
     q: jax.Array,           # (B, K, H, d) K verify tokens per slot
-    k_pages: jax.Array,     # (P, page, Hkv, d) shared block pool
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d) shared block pool
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32
     seq_lens: jax.Array,    # (B,) int32 pre-step KV length; 0 = free slot
     chunk_lens: jax.Array,  # (B,) int32 real input count this step
@@ -541,15 +556,16 @@ def paged_verify_attention(
     causally to KV ``<= seq_lens[b] + i`` (and ``< seq_lens[b] +
     chunk_lens[b]``), so all K positions are scored in one page-table
     walk.  With K == 1 this computes exactly
-    :func:`paged_decode_attention` on the post-append cache.  On TPU the
-    dedicated verify kernel walks the table with scalar prefetch;
-    elsewhere the jnp gather path reuses the grouped chunk math (same
-    numerics as the decode path, which is what makes k-step spec decode
-    token-exact).  Rows at ``i >= chunk_lens[b]`` are garbage the caller
-    ignores; ``chunk_lens[b] == 0`` rows come back zero.
+    :func:`paged_decode_attention` on the post-append cache.  On the TPU
+    the dedicated verify kernel walks the table with scalar prefetch, on
+    both rails; elsewhere the jnp gather path reuses the grouped chunk
+    math (same numerics as the decode path, which is what makes k-step
+    spec decode token-exact).  Rows at ``i >= chunk_lens[b]`` are
+    garbage the caller ignores; ``chunk_lens[b] == 0`` rows come back
+    zero.
     """
     b, kw, h, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = h // hkv
     use_hfa = impl.startswith("hfa")
     qg = jnp.swapaxes(q, 1, 2).reshape(b, hkv, g, kw, d)

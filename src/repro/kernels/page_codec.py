@@ -4,7 +4,7 @@ The serving engine's KV pools were "one array per layer in the compute
 dtype".  A :class:`PageCodec` generalizes that layout into encode-on-
 write / decode-in-kernel: the pools hold *encoded* pages in the codec's
 storage dtype, an optional per-page **scale sidecar** rides next to them
-(same (P, page, Hkv, ·) rank, trailing dim 1, so every page-table
+(same (P, Hkv, page, ·) rank, trailing dim 1, so every page-table
 mechanism - scatter writers, COW ``copy_pages``, ``gather_pages``, the
 TP ``NamedSharding`` placement - applies to scale leaves unchanged),
 and the paged kernels dequantize inside the tile loop right after the

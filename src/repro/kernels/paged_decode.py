@@ -1,9 +1,15 @@
 """Paged flash-decode Pallas kernel + device-side page-table cache ops.
 
 The serving engine stores each layer's KV cache as a shared *block pool*
-of fixed-size pages, ``(num_pages, page_size, Hkv, d)``, addressed by a
+of fixed-size pages, ``(num_pages, Hkv, page_size, d)``, addressed by a
 per-sequence page table ``(B, pages_per_seq)`` - vLLM's PagedAttention
 layout mapped onto the paper's multi-KV-block FAU architecture (Fig. 2):
+
+  * Pools are head-major: the kernel's K/V block ``(1, 1, page, d)`` is
+    one (page, d) tile per (page, kv head), which satisfies Mosaic's
+    block rule (the last two block dims divisible by 8 and 128) for the
+    page sizes in :data:`TPU_PAGE_SIZES`.  A bf16 page of 16 is exactly
+    one native (16, 128) tile.
 
   * Every page is one KV block.  The kernel walks a sequence's page
     table with scalar prefetch (the page id feeds the BlockSpec index
@@ -29,9 +35,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 from repro.kernels import bitmath
 from repro.kernels.decode import LANES, NEG_INF
+
+# Page sizes the paged kernels compile for on the TPU (checked against a
+# described v5e by tests/test_tpu_compile.py).  The (page, d) K/V tile
+# needs a page that is a multiple of 8 sublanes; 8 and 16 are the sizes
+# the serving stack uses.
+TPU_PAGE_SIZES = (8, 16)
 
 
 def _load_tile(codec, ref, s_ref):
@@ -41,10 +52,10 @@ def _load_tile(codec, ref, s_ref):
     pre-codec kernel); otherwise the codec's decode runs inside the tile
     loop, with the per-page scale tile (page, 1) from the sidecar pool.
     """
-    tile = ref[0, :, 0, :]
+    tile = ref[0, 0]
     if codec is None:
         return tile.astype(jnp.float32)
-    s = None if s_ref is None else s_ref[0, :, 0, :].astype(jnp.float32)
+    s = None if s_ref is None else s_ref[0, 0].astype(jnp.float32)
     return codec.decode(tile, s).astype(jnp.float32)
 
 
@@ -100,16 +111,16 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
 
 def paged_decode_partial_pallas(
     q: jax.Array,           # (B, Hkv, G, d) grouped queries
-    k_pages: jax.Array,     # (P, page, Hkv, d) shared block pool
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d) shared block pool
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32 page ids
     kv_lens: jax.Array,     # (B,) int32 valid KV length per sequence
     *,
+    interpret: bool,
     scale: float | None = None,
     use_hfa: bool = False,
-    interpret: bool = True,
     codec=None,
-    k_scales: jax.Array | None = None,  # (P, page, Hkv, 1) f32 sidecar
+    k_scales: jax.Array | None = None,  # (P, Hkv, page, 1) f32 sidecar
     v_scales: jax.Array | None = None,
 ):
     """Partial paged decode attention: one block-FAU triplet per (b, hkv).
@@ -129,7 +140,7 @@ def paged_decode_partial_pallas(
       triplets via :func:`repro.kernels.decode.merge_partials`.
     """
     b, hkv, g, d = q.shape
-    _, page_size, hkv_p, _ = k_pages.shape
+    _, hkv_p, page_size, _ = k_pages.shape
     assert hkv_p == hkv, (hkv_p, hkv)
     pages_per_seq = page_table.shape[1]
     scale_v = (1.0 / d ** 0.5) if scale is None else scale
@@ -139,18 +150,18 @@ def paged_decode_partial_pallas(
                                scale=scale_v, use_hfa=use_hfa, codec=codec)
     in_specs = [
         pl.BlockSpec((1, 1, g, d), lambda b, h, j, pt, sl: (b, h, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, j, pt, sl: (pt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, j, pt, sl: (pt[b, j], 0, h, 0)),
+        pl.BlockSpec((1, 1, page_size, d),
+                     lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
+        pl.BlockSpec((1, 1, page_size, d),
+                     lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
     ]
     operands = [q, k_pages, v_pages]
     if has_scales:
         in_specs += [
-            pl.BlockSpec((1, page_size, 1, 1),
-                         lambda b, h, j, pt, sl: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, 1),
-                         lambda b, h, j, pt, sl: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, 1),
+                         lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, 1),
+                         lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
         ]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -176,7 +187,7 @@ def paged_decode_partial_pallas(
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_flash_decode_partial",
@@ -185,15 +196,26 @@ def paged_decode_partial_pallas(
 
 
 # ------------------------------------------------------- page cache ops
-def _flat_write_pos(page_table, positions, page_size):
-    """Pool-flat write index for (b, position): table[b, pos//page] * page
-    + pos % page.  positions: (B,) or (B, L).  Page indices are clamped
-    to the table width so padded positions past the allocation resolve
-    to a (wrong but in-bounds) page - callers that can produce them
-    (write_chunk_kv) drop those writes explicitly."""
+def _write_rows(k_pages, v_pages, k_rows, v_rows, page, off):
+    """Scatter token rows into head-major pools: row i (Hkv, d) lands
+    at ``pages[page[i], :, off[i]]``.  Rows whose ``page`` is out of
+    range (the pool size) are dropped."""
+    kp = k_pages.at[page, :, off].set(k_rows.astype(k_pages.dtype),
+                                      mode="drop")
+    vp = v_pages.at[page, :, off].set(v_rows.astype(v_pages.dtype),
+                                      mode="drop")
+    return kp, vp
+
+
+def _page_coords(page_table, positions, page_size):
+    """(page id, in-page offset) of each (b, position).  positions: (B,)
+    or (B, L).  Page indices are clamped to the table width so padded
+    positions past the allocation resolve to a (wrong but in-bounds)
+    page - callers that can produce them (write_chunk_kv) drop those
+    writes explicitly."""
     pidx = jnp.minimum(positions // page_size, page_table.shape[1] - 1)
     page = jnp.take_along_axis(page_table, pidx, axis=1)
-    return page * page_size + positions % page_size
+    return page, positions % page_size
 
 
 def append_kv(k_pages, v_pages, k_new, v_new, page_table, seq_lens):
@@ -203,15 +225,12 @@ def append_kv(k_pages, v_pages, k_new, v_new, page_table, seq_lens):
     position ``seq_lens[b]``.  Slots with ``seq_lens[b] == 0`` are free
     (nothing has been prefilled) and their write is dropped.
     """
-    p, page_size, hkv, d = k_pages.shape
+    p, _, page_size, _ = k_pages.shape
     pos = seq_lens.astype(jnp.int32)
-    flat = _flat_write_pos(page_table, pos[:, None], page_size)[:, 0]
-    flat = jnp.where(pos > 0, flat, p * page_size)     # OOB => dropped
-    kf = k_pages.reshape(p * page_size, hkv, d)
-    vf = v_pages.reshape(p * page_size, hkv, d)
-    kf = kf.at[flat].set(k_new[:, 0].astype(kf.dtype), mode="drop")
-    vf = vf.at[flat].set(v_new[:, 0].astype(vf.dtype), mode="drop")
-    return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)
+    page, off = _page_coords(page_table, pos[:, None], page_size)
+    page = jnp.where(pos > 0, page[:, 0], p)            # OOB => dropped
+    return _write_rows(k_pages, v_pages, k_new[:, 0], v_new[:, 0], page,
+                       off[:, 0])
 
 
 def write_prefill_kv(k_pages, v_pages, k_new, v_new, page_table):
@@ -222,20 +241,20 @@ def write_prefill_kv(k_pages, v_pages, k_new, v_new, page_table):
     of equal-length requests), padding to a page multiple; padded tail
     positions are masked later by ``kv_lens``.
     """
-    p, page_size, hkv, d = k_pages.shape
-    b, l, _, _ = k_new.shape
+    page_size = k_pages.shape[2]
+    b, l, hkv, d = k_new.shape
     pos = jnp.broadcast_to(jnp.arange(l, dtype=jnp.int32)[None], (b, l))
-    flat = _flat_write_pos(page_table, pos, page_size).reshape(-1)
-    kf = k_pages.reshape(p * page_size, hkv, d)
-    vf = v_pages.reshape(p * page_size, hkv, d)
-    kf = kf.at[flat].set(k_new.reshape(b * l, hkv, d).astype(kf.dtype))
-    vf = vf.at[flat].set(v_new.reshape(b * l, hkv, d).astype(vf.dtype))
-    return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)
+    page, off = _page_coords(page_table, pos, page_size)
+    return _write_rows(k_pages, v_pages, k_new.reshape(b * l, hkv, d),
+                       v_new.reshape(b * l, hkv, d), page.reshape(-1),
+                       off.reshape(-1))
 
 
 def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
-    """Dense (B, pages_per_seq * page, Hkv, d) view of each sequence's KV."""
+    """Dense (B, pages_per_seq * page, Hkv, d) view of each sequence's KV
+    (sequence-major, the layout of the dense jnp paths and oracles)."""
     b, j = page_table.shape
-    _, page_size, hkv, d = pages.shape
+    _, hkv, page_size, d = pages.shape
     out = jnp.take(pages, page_table.reshape(-1), axis=0)
+    out = jnp.swapaxes(out.reshape(b, j, hkv, page_size, d), 2, 3)
     return out.reshape(b, j * page_size, hkv, d)

@@ -29,10 +29,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 from repro.kernels import bitmath
 from repro.kernels.decode import LANES, NEG_INF
-from repro.kernels.paged_decode import _flat_write_pos, _load_tile
+from repro.kernels.paged_decode import _load_tile, _page_coords, _write_rows
 
 
 def _paged_prefill_kernel(pt_ref, sp_ref, kl_ref, q_ref, k_ref, v_ref,
@@ -91,17 +90,17 @@ def _paged_prefill_kernel(pt_ref, sp_ref, kl_ref, q_ref, k_ref, v_ref,
 
 def paged_prefill_partial_pallas(
     q: jax.Array,           # (B, Hkv, G, L, d) grouped chunk queries
-    k_pages: jax.Array,     # (P, page, Hkv, d) shared block pool
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d) shared block pool
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32 page ids
     start_pos: jax.Array,   # (B,) int32 chunk start position per sequence
     kv_lens: jax.Array,     # (B,) int32 valid KV length (start + chunk len)
     *,
+    interpret: bool,
     scale: float | None = None,
     use_hfa: bool = False,
-    interpret: bool = True,
     codec=None,
-    k_scales: jax.Array | None = None,  # (P, page, Hkv, 1) f32 sidecar
+    k_scales: jax.Array | None = None,  # (P, Hkv, page, 1) f32 sidecar
     v_scales: jax.Array | None = None,
 ):
     """Partial paged chunked-prefill attention.
@@ -121,7 +120,7 @@ def paged_prefill_partial_pallas(
       :mod:`repro.kernels.decode`.
     """
     b, hkv, g, chunk, d = q.shape
-    _, page_size, hkv_p, _ = k_pages.shape
+    _, hkv_p, page_size, _ = k_pages.shape
     assert hkv_p == hkv, (hkv_p, hkv)
     pages_per_seq = page_table.shape[1]
     scale_v = (1.0 / d ** 0.5) if scale is None else scale
@@ -135,18 +134,18 @@ def paged_prefill_partial_pallas(
     in_specs = [
         pl.BlockSpec((1, 1, rows, d),
                      lambda b, h, j, pt, sp, kl: (b, h, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, j, pt, sp, kl: (pt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, j, pt, sp, kl: (pt[b, j], 0, h, 0)),
+        pl.BlockSpec((1, 1, page_size, d),
+                     lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
+        pl.BlockSpec((1, 1, page_size, d),
+                     lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
     ]
     operands = [q3, k_pages, v_pages]
     if has_scales:
         in_specs += [
-            pl.BlockSpec((1, page_size, 1, 1),
-                         lambda b, h, j, pt, sp, kl: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, 1),
-                         lambda b, h, j, pt, sp, kl: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, 1),
+                         lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, 1),
+                         lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
         ]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -175,7 +174,7 @@ def paged_prefill_partial_pallas(
             jax.ShapeDtypeStruct((b, hkv, rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, rows, 1), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_prefill_partial",
@@ -197,21 +196,16 @@ def write_chunk_kv(k_pages, v_pages, k_new, v_new, page_table, start_pos,
     never touches positions outside the chunk, so shared prefix pages
     below ``start_pos`` and pages beyond the chunk stay intact.
     """
-    p, page_size, hkv, d = k_pages.shape
-    b, l, _, _ = k_new.shape
+    p, _, page_size, _ = k_pages.shape
+    b, l, hkv, d = k_new.shape
     offs = jnp.arange(l, dtype=jnp.int32)[None]                # (1, L)
     pos = start_pos.astype(jnp.int32)[:, None] + offs          # (B, L)
-    flat = _flat_write_pos(page_table.astype(jnp.int32), pos, page_size)
+    page, off = _page_coords(page_table.astype(jnp.int32), pos, page_size)
     valid = offs < chunk_lens.astype(jnp.int32)[:, None]
-    flat = jnp.where(valid, flat, p * page_size)               # OOB => drop
-    flat = flat.reshape(-1)
-    kf = k_pages.reshape(p * page_size, hkv, d)
-    vf = v_pages.reshape(p * page_size, hkv, d)
-    kf = kf.at[flat].set(k_new.reshape(b * l, hkv, d).astype(kf.dtype),
-                         mode="drop")
-    vf = vf.at[flat].set(v_new.reshape(b * l, hkv, d).astype(vf.dtype),
-                         mode="drop")
-    return kf.reshape(k_pages.shape), vf.reshape(v_pages.shape)
+    page = jnp.where(valid, page, p)                           # OOB => drop
+    return _write_rows(k_pages, v_pages, k_new.reshape(b * l, hkv, d),
+                       v_new.reshape(b * l, hkv, d), page.reshape(-1),
+                       off.reshape(-1))
 
 
 def copy_pages(pages: jax.Array, src: jax.Array, dst: jax.Array,
@@ -220,7 +214,7 @@ def copy_pages(pages: jax.Array, src: jax.Array, dst: jax.Array,
     along ``axis``.  Padding entries use an out-of-range ``dst`` (the
     write is dropped); ``src`` is clipped so the dead gather stays in
     bounds.  ``axis`` selects the page dimension (1 for the stacked
-    (groups, P, page, Hkv, d) layer pools)."""
+    (groups, P, Hkv, page, d) layer pools)."""
     vals = jnp.take(pages, src.astype(jnp.int32), axis=axis, mode="clip")
     idx = (slice(None),) * axis + (dst.astype(jnp.int32),)
     return pages.at[idx].set(vals, mode="drop")

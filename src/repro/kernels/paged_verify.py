@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import pallas_compat
 from repro.kernels import bitmath
 from repro.kernels.decode import LANES, NEG_INF
 from repro.kernels.paged_decode import _load_tile, gather_pages
@@ -100,17 +99,17 @@ def _paged_verify_kernel(pt_ref, sl_ref, cl_ref, q_ref, k_ref, v_ref,
 
 def paged_verify_partial_pallas(
     q: jax.Array,           # (B, Hkv, G, K, d) grouped verify queries
-    k_pages: jax.Array,     # (P, page, Hkv, d) shared block pool
-    v_pages: jax.Array,     # (P, page, Hkv, d)
+    k_pages: jax.Array,     # (P, Hkv, page, d) shared block pool
+    v_pages: jax.Array,     # (P, Hkv, page, d)
     page_table: jax.Array,  # (B, pages_per_seq) int32 page ids
     seq_lens: jax.Array,    # (B,) int32 pre-step KV length per sequence
     chunk_lens: jax.Array,  # (B,) int32 real input count this step (0=free)
     *,
+    interpret: bool,
     scale: float | None = None,
     use_hfa: bool = False,
-    interpret: bool = True,
     codec=None,
-    k_scales: jax.Array | None = None,  # (P, page, Hkv, 1) f32 sidecar
+    k_scales: jax.Array | None = None,  # (P, Hkv, page, 1) f32 sidecar
     v_scales: jax.Array | None = None,
 ):
     """Partial paged verify attention: one block-FAU triplet per
@@ -124,7 +123,7 @@ def paged_verify_partial_pallas(
       :mod:`repro.kernels.decode`.
     """
     b, hkv, g, spec_width, d = q.shape
-    _, page_size, hkv_p, _ = k_pages.shape
+    _, hkv_p, page_size, _ = k_pages.shape
     assert hkv_p == hkv, (hkv_p, hkv)
     pages_per_seq = page_table.shape[1]
     scale_v = (1.0 / d ** 0.5) if scale is None else scale
@@ -138,18 +137,18 @@ def paged_verify_partial_pallas(
     in_specs = [
         pl.BlockSpec((1, 1, rows, d),
                      lambda b, h, j, pt, sl, cl: (b, h, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, j, pt, sl, cl: (pt[b, j], 0, h, 0)),
-        pl.BlockSpec((1, page_size, 1, d),
-                     lambda b, h, j, pt, sl, cl: (pt[b, j], 0, h, 0)),
+        pl.BlockSpec((1, 1, page_size, d),
+                     lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
+        pl.BlockSpec((1, 1, page_size, d),
+                     lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
     ]
     operands = [q3, k_pages, v_pages]
     if has_scales:
         in_specs += [
-            pl.BlockSpec((1, page_size, 1, 1),
-                         lambda b, h, j, pt, sl, cl: (pt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, 1),
-                         lambda b, h, j, pt, sl, cl: (pt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, 1),
+                         lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, 1),
+                         lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
         ]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -178,7 +177,7 @@ def paged_verify_partial_pallas(
             jax.ShapeDtypeStruct((b, hkv, rows, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, rows, 1), jnp.float32),
         ],
-        compiler_params=pallas_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_verify_partial",
@@ -195,8 +194,9 @@ def paged_verify_partial_ref(q, k_pages, v_pages, page_table, seq_lens,
     """jnp triplet oracle: dense gather + one-shot softmax pieces.
 
     Same signature/returns as :func:`paged_verify_partial_pallas`.  The
-    running max equals the global max, so ``m`` matches the kernel
-    exactly; ``l``/``o~`` differ only by f32 summation order.  With a
+    running max equals the global max, so ``m`` differs from the
+    kernel's only by the order in which the score dot sums its d
+    products; ``l``/``o~`` differ by f32 summation order.  With a
     ``codec`` the gathered pages (and sidecar scales) are decoded before
     the dense softmax - the same decode the kernel applies per tile.
     """

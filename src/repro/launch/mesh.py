@@ -7,6 +7,17 @@ the dry-run sees 512 placeholders).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes.  The sharding rules of this repo
+    (``with_sharding_constraint`` on logical axes, gathers over sharded
+    operands) leave placement to the compiler's propagation, which is
+    what Auto axes mean; ``jax.make_mesh`` makes Explicit axes by
+    default, under which those gathers raise ``ShardingTypeError``."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -17,14 +28,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(devices: int | None = None):
     """Small mesh over whatever devices exist (tests: 8 fake CPU devices)."""
     n = devices or len(jax.devices())
     model = 2 if n % 2 == 0 else 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def make_tp_mesh(tp: int):
@@ -43,10 +54,11 @@ def make_tp_mesh(tp: int):
     return Mesh(np.asarray(devs[:tp]).reshape(1, tp), ("data", "model"))
 
 
-def make_tp_dp_mesh(tp: int, dp: int):
+def make_tp_dp_mesh(tp: int, dp: int, offset: int = 0):
     """Composed serving mesh: ("data"=dp, "model"=tp) over the first
-    ``dp * tp`` devices.  The "model" axis KV-head-shards the paged
-    pools (tensor parallelism, PR 4); the "data" axis batch-shards the
+    ``dp * tp`` devices (from ``offset`` on: one mesh per router
+    replica on a larger host).  The "model" axis KV-head-shards the paged
+    pools (tensor parallelism); the "data" axis batch-shards the
     *slot* dimension of every paged attention call, so a step's compute
     splits across data shards while the pools (replicated over "data")
     and the host page tables stay bit-identical on every shard.  On
@@ -58,7 +70,7 @@ def make_tp_dp_mesh(tp: int, dp: int):
     from jax.sharding import Mesh
     if tp < 1 or dp < 1:
         raise ValueError(f"tp and dp must be >= 1, got tp={tp} dp={dp}")
-    devs = jax.devices()
+    devs = jax.devices()[offset:]
     need = dp * tp
     if len(devs) < need:
         raise RuntimeError(

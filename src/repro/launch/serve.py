@@ -167,8 +167,10 @@ def main():
 
     from repro.configs import get_config
     from repro.data import DataPipeline
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import build_model
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -173,9 +173,12 @@ def main():
 
     from repro.configs import get_config
     from repro.data import DataPipeline
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import build_model
     from repro.serving import (LATENCY_CLASSES, AsyncFrontend, Request,
                                SamplingParams, ServingEngine)
+
+    enable_compile_cache()
 
     for name in args.class_mix:
         if name not in LATENCY_CLASSES:

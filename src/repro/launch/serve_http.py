@@ -188,6 +188,51 @@ async def _smoke_client(server, cfg) -> list[str]:
     return fails
 
 
+def build_server(model, params, *, batch: int, page_size: int,
+                 max_seq: int, prefill_budget, spec_k: int = 0,
+                 kv_codec: str = "fp", tp: int = 1, dp: int = 1,
+                 replicas: int = 1, stream_buffer: int = 1024,
+                 max_results: int = 1024, host: str = "127.0.0.1",
+                 port: int = 0, queue_caps=None,
+                 drain_timeout: float = 30.0):
+    """The serving stack this entry point runs: ``ServingEngine`` ->
+    ``AsyncFrontend`` (-> ``Router`` over ``replicas`` of them) ->
+    ``HttpServer``.  Call it inside the event loop that will serve.
+
+    Placement: each replica takes the next ``tp * dp`` devices when
+    there are enough for all of them (replica i's params, pools and
+    jitted steps then live on its own devices); otherwise the replicas
+    share the first ones.  Returns (server, frontends); the caller
+    starts the server and closes the frontends."""
+    import jax
+
+    from repro.serving import AsyncFrontend, ServingEngine
+    from repro.serving.http import HttpServer
+    from repro.serving.router import Router
+
+    per = tp * dp
+    own = len(jax.devices()) >= replicas * per
+    engines = []
+    for i in range(replicas):
+        off = i * per if own else 0
+        mesh = device = None
+        if per > 1:
+            from repro.launch.mesh import make_tp_dp_mesh
+            mesh = make_tp_dp_mesh(tp, dp, offset=off)
+        elif replicas > 1 and own:
+            device = jax.devices()[off]
+        engines.append(ServingEngine(
+            model, params, max_batch=batch, page_size=page_size,
+            max_seq=max_seq, prefill_budget=prefill_budget, spec_k=spec_k,
+            mesh=mesh, kv_codec=kv_codec, device=device))
+    frontends = [AsyncFrontend(e, stream_buffer=stream_buffer,
+                               max_results=max_results) for e in engines]
+    frontend = frontends[0] if replicas == 1 else Router(frontends)
+    server = HttpServer(frontend, host=host, port=port,
+                        queue_caps=queue_caps, drain_timeout=drain_timeout)
+    return server, frontends
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
@@ -241,11 +286,10 @@ def main():
     import jax
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import build_model
-    from repro.serving import AsyncFrontend, ServingEngine
-    from repro.serving.http import HttpServer
-    from repro.serving.router import Router
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -254,30 +298,18 @@ def main():
                          "front-end has no dense fallback")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    mesh = None
-    if args.tp > 1 or args.dp > 1:
-        from repro.launch.mesh import make_tp_dp_mesh
-        mesh = make_tp_dp_mesh(args.tp, args.dp)
-    engines = [ServingEngine(model, params, max_batch=args.batch,
-                             page_size=args.page_size,
-                             max_seq=args.max_seq,
-                             prefill_budget=args.prefill_budget,
-                             spec_k=args.spec_k, mesh=mesh,
-                             kv_codec=args.kv_codec)
-               for _ in range(args.replicas)]
-    engine = engines[0]
 
     async def run() -> int:
-        frontends = [AsyncFrontend(e,
-                                   stream_buffer=args.stream_buffer,
-                                   max_results=args.max_results)
-                     for e in engines]
-        frontend = frontends[0] if args.replicas == 1 \
-            else Router(frontends)
-        server = HttpServer(frontend, host=args.host,
-                            port=0 if args.smoke else args.port,
-                            queue_caps=args.queue_cap,
-                            drain_timeout=args.drain_timeout)
+        server, frontends = build_server(
+            model, params, batch=args.batch, page_size=args.page_size,
+            max_seq=args.max_seq, prefill_budget=args.prefill_budget,
+            spec_k=args.spec_k, kv_codec=args.kv_codec, tp=args.tp,
+            dp=args.dp, replicas=args.replicas,
+            stream_buffer=args.stream_buffer,
+            max_results=args.max_results, host=args.host,
+            port=0 if args.smoke else args.port,
+            queue_caps=args.queue_cap, drain_timeout=args.drain_timeout)
+        engine = frontends[0].engine
         await server.start()
         print(f"serving {cfg.name} on http://{server.host}:{server.port} "
               f"(batch {args.batch}, page {args.page_size}, codec "

@@ -256,8 +256,8 @@ class LM:
                     f"tp={tp}")
             from jax.sharding import NamedSharding
             from jax.sharding import PartitionSpec as P
-            # Stacked pools are (groups, P, page, Hkv, dh): head axis 3.
-            sh = NamedSharding(mesh, P(None, None, None, "model", None))
+            # Stacked pools are (groups, P, Hkv, page, dh): head axis 2.
+            sh = NamedSharding(mesh, P(None, None, "model", None, None))
             layers = jax.device_put(layers, sh)
         return layers
 

@@ -239,7 +239,7 @@ def stack_apply(params, x, cfg, *, positions=None, caches=None,
 
 def stack_init_paged_cache(cfg, num_pages: int, page_size: int, dtype,
                            codec: str = "fp"):
-    """Paged block-pool caches, stacked (groups, P, page, Hkv, dh).
+    """Paged block-pool caches, stacked (groups, P, Hkv, page, dh).
 
     One shared pool per layer; sequences address it through the
     engine-owned page table, so no per-slot ``max_seq`` is reserved.
@@ -262,7 +262,7 @@ def stack_init_paged_cache(cfg, num_pages: int, page_size: int, dtype,
     sdt = c.storage_dtype(dtype)
 
     def one_layer():
-        shape = (groups, num_pages, page_size, cfg.n_kv_heads, cfg.d_head)
+        shape = (groups, num_pages, cfg.n_kv_heads, page_size, cfg.d_head)
         leaves = {"k_pages": jnp.zeros(shape, sdt),
                   "v_pages": jnp.zeros(shape, sdt)}
         if c.has_scales:

@@ -20,28 +20,12 @@ pattern.
 """
 from __future__ import annotations
 
-import functools
-import inspect
-
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import decode as dk
-
-try:                                     # jax >= 0.6
-    from jax import shard_map as _shard_map_impl
-except ImportError:                      # older jax: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def shard_map(*args, **kwargs):
-    """shard_map across jax versions: translate the ``check_vma`` kwarg
-    to its pre-rename spelling ``check_rep`` when needed."""
-    params = inspect.signature(_shard_map_impl).parameters
-    if "check_vma" in kwargs and "check_vma" not in params:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map_impl(*args, **kwargs)
 
 
 def shardmap_decode_attention(
@@ -132,7 +116,7 @@ def shardmap_paged_attention(
     k_new: jax.Array,    # (B, L, Hkv, dh) this step's K/V to scatter
     v_new: jax.Array,    # (B, L, Hkv, dh)
     pools: dict,         # {"k_pages", "v_pages"[, "k_scale", "v_scale"]}
-                         # each (P, page, Hkv, ·), Hkv sharded over `axis`
+                         # each (P, Hkv, page, ·), Hkv sharded over `axis`
     page_table: jax.Array,  # (B, pages_per_seq) int32, replicated
     lens_a: jax.Array,   # (B,) int32: decode/verify seq_lens; prefill start
     lens_b: jax.Array,   # (B,) int32: verify/prefill chunk_lens; decode 0s
@@ -205,7 +189,7 @@ def shardmap_paged_attention(
 
     assert mode in ("decode", "verify", "prefill"), mode
     b, l_q, h, dh = q.shape
-    hkv = pools["k_pages"].shape[2]
+    hkv = pools["k_pages"].shape[1]
     g = h // hkv
     n = tp_shards(mesh, axis)
     assert hkv % n == 0, (
@@ -295,16 +279,17 @@ def shardmap_paged_attention(
         return out.astype(q.dtype), pools
 
     # hspec is a pytree *prefix* for the pools dict: every pool leaf
-    # (data or scale sidecar) is (P, page, Hkv, ·) with Hkv at axis 2.
+    # (data or scale sidecar) is (P, Hkv, page, ·) with Hkv at axis 1.
     # Nothing names the data axis except q/out's batch dim: the pools
     # and the scatter operands stay replicated over "data" so every
     # data shard's pool replica evolves identically.
-    hspec = P(None, None, axis, None)
+    hspec = P(None, axis, None, None)
+    kvspec = P(None, None, axis, None)          # (B, L, Hkv, dh) new K/V
     dspec = data_axis if shard_b else None
     qspec = P(dspec, None, axis, None)
     fn = shard_map(
         local, mesh=mesh,
-        in_specs=(qspec, hspec, hspec, hspec, P(), P(), P()),
+        in_specs=(qspec, kvspec, kvspec, hspec, P(), P(), P()),
         out_specs=(P(dspec), hspec),
         check_vma=False)
     return fn(q, k_new, v_new, dict(pools), page_table,

@@ -51,7 +51,7 @@ from repro.serving.scheduler import FinishedRequest, Request
 
 
 def _copy_across(src_layers, dst_layers, src, dst):
-    # Layer pools are stacked (groups, P, page, Hkv, d): page-id axis 1.
+    # Layer pools are stacked (groups, P, Hkv, page, d): page-id axis 1.
     # ``dst`` rows padded past the pool are dropped (jit scatter mode).
     return jax.tree.map(
         lambda s, d: d.at[:, dst].set(jnp.take(s, src, axis=1)),

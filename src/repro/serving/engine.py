@@ -72,7 +72,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
+
 from repro.kernels import page_codec
+from repro.kernels import paged_decode as paged_k
 from repro.kernels import paged_prefill as paged_pf_k
 from repro.serving import sampler
 from repro.serving.paged_cache import PagedKVCache
@@ -93,8 +97,9 @@ def _serving_jits(model, mesh=None, codec="fp"):
     by the tensor-parallel mesh (None = single shard) and the page
     codec - a TP engine and a single-shard engine over the same model
     trace different attention paths, and each codec bakes a different
-    encode/decode into the trace.  Cache donation is skipped on CPU,
-    where it is unsupported and only adds dispatch overhead."""
+    encode/decode into the trace.  The layer pools are donated on every
+    backend, so each step updates them in place and the CPU suites
+    exercise the same buffer aliasing as the chip."""
     cache = getattr(model, "_serving_jits_v5", None)
     if cache is None:
         cache = model._serving_jits_v5 = {}
@@ -220,22 +225,20 @@ def _serving_jits(model, mesh=None, codec="fp"):
         return toks, tkv, tki, lp, layers
 
     def copy_fn(layers, src, dst):
-        # Layer pools are stacked (groups, P, page, Hkv, d): page axis 1.
+        # Layer pools are stacked (groups, P, Hkv, page, d): page axis 1.
         return jax.tree.map(
             lambda pool: paged_pf_k.copy_pages(pool, src, dst, axis=1),
             layers)
 
-    cpu = jax.default_backend() == "cpu"
-    donate = () if cpu else (1,)
-    jits = (jax.jit(prefill_fn, donate_argnums=donate),
-            jax.jit(decode_fn, donate_argnums=donate,
+    jits = (jax.jit(prefill_fn, donate_argnums=(1,)),
+            jax.jit(decode_fn, donate_argnums=(1,),
                     static_argnums=(12, 13, 14)),
-            jax.jit(verify_fn, donate_argnums=donate,
+            jax.jit(verify_fn, donate_argnums=(1,),
                     static_argnums=(12, 13, 14)),
-            jax.jit(copy_fn, donate_argnums=() if cpu else (0,)),
+            jax.jit(copy_fn, donate_argnums=(0,)),
             jax.jit(sample_fn, static_argnums=(8, 9)),
             jax.jit(topk_fn, static_argnums=(1,)),
-            jax.jit(prefill_lp_fn, donate_argnums=donate))
+            jax.jit(prefill_lp_fn, donate_argnums=(1,)))
     cache[(mesh, codec)] = jits
     return jits
 
@@ -254,9 +257,17 @@ class ServingEngine:
                  cached_frac: float = 0.5,
                  adaptive_floor: int | None = None,
                  adaptive_ceiling: int | None = None,
-                 mesh=None, kv_codec: str = "fp"):
+                 mesh=None, kv_codec: str = "fp", device=None):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
+        if jax.default_backend() == "tpu" and \
+                page_size not in paged_k.TPU_PAGE_SIZES:
+            raise ValueError(
+                f"page_size {page_size} is not supported on the TPU: the "
+                f"paged kernels compile for page sizes "
+                f"{paged_k.TPU_PAGE_SIZES}")
+        if mesh is not None and device is not None:
+            raise ValueError("pass a mesh or a device, not both")
         # Page codec: how KV rows are stored in the device pools ("fp"
         # = raw compute dtype, "int8" = per-row absmax quant + f32 scale
         # sidecar, "log16" = 16-bit log-domain).  Validated here so a
@@ -297,7 +308,16 @@ class ServingEngine:
         # attention call on the slot dim (pools and host state stay
         # replicated across data shards - see
         # repro.parallel.collectives).
+        # Placement: a mesh replicates the params over its devices (the
+        # pools are head-sharded in init_paged_cache); a device commits
+        # params and pools to that one device, so the jitted steps run
+        # there (one replica per chip behind a Router).  With neither,
+        # everything stays on the default device.
         self.mesh = mesh
+        if mesh is not None:
+            params = jax.device_put(params, NamedSharding(mesh, P()))
+        elif device is not None:
+            params = jax.device_put(params, device)
         self.tp = 1 if mesh is None else int(mesh.shape.get("model", 1))
         self.dp = 1 if mesh is None else int(mesh.shape.get("data", 1))
         if self.tp > 1 or self.dp > 1:
@@ -352,9 +372,12 @@ class ServingEngine:
                                   self.pages_per_seq,
                                   max_cached_pages=max_cached)
         self.sched = Scheduler(self.cache)
-        self.layers = model.init_paged_cache(num_pages, page_size,
-                                             mesh=mesh,
-                                             codec=self.kv_codec)
+        with jax.default_device(device):
+            self.layers = model.init_paged_cache(num_pages, page_size,
+                                                 mesh=mesh,
+                                                 codec=self.kv_codec)
+        if device is not None:
+            self.layers = jax.device_put(self.layers, device)
         # Per-slot sampling state (greedy defaults), mirrored to device
         # every step; presence is the repetition-penalty context bitmask.
         self._temp = np.zeros((max_batch,), np.float32)
@@ -417,6 +440,48 @@ class ServingEngine:
                 per_dev[s.device] = per_dev.get(s.device, 0) + \
                     s.data.nbytes
         return max(per_dev.values())
+
+    def prompt_logits(self, prompt: list[int]) -> np.ndarray:
+        """Last-position logits (f32, padded vocab) of ``prompt`` from
+        this engine's own jitted prefill step - same params, pools,
+        codec and chunk shapes as serving a request with this prompt in
+        one chunk - computed in a scratch slot that is freed again.
+        Call between steps, with a slot and the prompt's pages free."""
+        n = len(prompt)
+        slot = self.cache.alloc_slot(n)
+        try:
+            lpad = -(-n // self.page_size) * self.page_size
+            width = self._pow2_width(self.cache.pages_for(n))
+            toks = np.zeros((1, lpad), np.int32)
+            toks[0, :n] = prompt
+            logits, self.layers = self._prefill(
+                self.params, self.layers, jnp.asarray(toks),
+                jnp.asarray(self.cache.page_table[slot:slot + 1, :width]),
+                jnp.zeros((1,), jnp.int32), jnp.asarray([n - 1], jnp.int32))
+            # Read the result before free_slot rewrites the table row: on
+            # the CPU the step may still be reading it in place.
+            return np.asarray(logits[0], np.float32)
+        finally:
+            self.cache.free_slot(slot)
+
+    def compiled_decode_text(self, max_tokens: int | None = None) -> str:
+        """HLO text of the compiled greedy decode step (spec_k == 0) or
+        verify step (spec_k > 0), at the page-table width serving uses
+        for sequences of up to ``max_tokens`` tokens (default: the
+        per-sequence ceiling) - for checking which kernels the step
+        served really runs."""
+        width = self.pages_per_seq if max_tokens is None else \
+            self._pow2_width(self.cache.pages_for(max_tokens))
+        kw = self.spec_k + 1
+        b = self.max_batch
+        zi = jnp.zeros((b,), jnp.int32)
+        zf = jnp.zeros((b,), jnp.float32)
+        step_fn = self._decode if kw == 1 else self._verify
+        return step_fn.lower(
+            self.params, self.layers, jnp.zeros((b, kw), jnp.int32),
+            jnp.asarray(self.cache.page_table[:, :width]), zi, zi, zi, zf,
+            zi, zf, zf,
+            jnp.asarray(_NO_PRESENCE), True, 0, False).compile().as_text()
 
     def _count_triplets(self, batch: int, rows: int) -> None:
         """Account the ACC-merge collective volume of one jitted call:

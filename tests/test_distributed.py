@@ -21,6 +21,7 @@ def _run(code: str) -> str:
 
 def test_sharded_train_step_matches_single_device():
     out = _run("""
+from repro.launch.mesh import make_mesh
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import get_config
@@ -43,7 +44,7 @@ carry = {"params": params, "opt_state": opt.init(params)}
 _, m1 = jax.jit(step)(carry, batch)
 
 # 4x2 mesh, sharded
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 sh.set_context(mesh, sh.TRAIN_RULES)
 shapes, logical = model.shape_and_logical()
 pspec = sh.tree_specs(logical, shapes, sh.TRAIN_RULES, mesh)
@@ -65,10 +66,11 @@ def test_kv_sharded_decode_matches_replicated():
     """Sequence-sharded KV cache decode == replicated decode (the paper's
     multi-KV-block parallelism at mesh level, XLA-merged)."""
     out = _run("""
+from repro.launch.mesh import make_mesh
 import jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.kernels import ops
-mesh = jax.make_mesh((1, 8), ("data", "model"))
+mesh = make_mesh((1, 8), ("data", "model"))
 rng = np.random.default_rng(0)
 q = jnp.asarray(rng.standard_normal((2, 1, 8, 64)), jnp.bfloat16)
 kc = jnp.asarray(rng.standard_normal((2, 512, 2, 64)), jnp.bfloat16)
@@ -92,13 +94,14 @@ def test_shardmap_decode_merge_matches_reference():
     """shard_map KV-split decode + explicit log-domain ACC merge (Eq. 16):
     the paper's cascaded merge as a cluster collective."""
     out = _run("""
+from repro.launch.mesh import make_mesh
 import jax, numpy as np, jax.numpy as jnp, functools
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.parallel.collectives import shard_map
 from repro.kernels import decode as dk
 from repro.core import reference as cref
 
-mesh = jax.make_mesh((8,), ("kv",))
+mesh = make_mesh((8,), ("kv",))
 rng = np.random.default_rng(0)
 BH, G, S, D = 4, 4, 1024, 64
 q = jnp.asarray(rng.standard_normal((BH, G, D)), jnp.bfloat16)
@@ -132,10 +135,11 @@ def test_shardmap_local_write_decode_attention():
     """parallel/collectives.py: local ring write + partial FAU + ACC merge
     must equal write-then-attend on one device (the §Perf mechanism)."""
     out = _run("""
+from repro.launch.mesh import make_mesh
 import jax, numpy as np, jax.numpy as jnp
 from repro.parallel.collectives import shardmap_decode_attention
 from repro.kernels import ops
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 B, S, H, HKV, D = 4, 512, 8, 2, 64
 q = jnp.asarray(rng.standard_normal((B,1,H,D)), jnp.bfloat16)
@@ -161,15 +165,16 @@ print("OK")
 def test_checkpoint_elastic_reshard():
     """Save under one sharding, restore under another mesh (elastic)."""
     out = _run("""
+from repro.launch.mesh import make_mesh
 import tempfile, jax, numpy as np, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import save, restore
 tree = {"w": jnp.arange(64.0).reshape(8, 8)}
 d = tempfile.mkdtemp()
-mesh1 = jax.make_mesh((8,), ("data",))
+mesh1 = make_mesh((8,), ("data",))
 t1 = jax.device_put(tree, NamedSharding(mesh1, P("data", None)))
 save(d, 1, t1)
-mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+mesh2 = make_mesh((2, 4), ("data", "model"))
 sh2 = {"w": NamedSharding(mesh2, P("model", "data"))}
 got, step = restore(d, None, tree, sh2)
 assert got["w"].sharding == sh2["w"]
@@ -182,11 +187,12 @@ print("OK")
 def test_small_dryrun_cell_on_8_devices():
     """The dry-run machinery works end-to-end on a small mesh."""
     out = _run("""
+from repro.launch.mesh import make_mesh
 import jax, json
 from repro.configs import get_config
 from repro.launch.specs import build_cell
 cfg = get_config("qwen3-1.7b").reduced()
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 for shape in ("train_4k", "decode_32k"):
     import dataclasses
     from repro.launch import specs

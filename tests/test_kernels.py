@@ -34,7 +34,8 @@ def test_fa2_kernel_vs_oracle(shape, dtype, causal):
     k = _rand((bh, lkv, d), dtype, 2)
     v = _rand((bh, lkv, d), dtype, 3)
     out = np.asarray(fa2.fa2_pallas(q, k, v, causal=causal,
-                                    block_q=bq, block_kv=bk))
+                                    block_q=bq, block_kv=bk,
+                                    interpret=True))
     gold = np.asarray(ref.ref_fa2(q, k, v, causal=causal))
     tol = 3e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(out, gold, atol=tol)
@@ -49,7 +50,8 @@ def test_hfa_kernel_matches_tile_oracle(shape, causal):
     k = _rand((bh, lkv, d), jnp.bfloat16, 5)
     v = _rand((bh, lkv, d), jnp.bfloat16, 6)
     out = np.asarray(hfa.hfa_pallas(q, k, v, causal=causal,
-                                    block_q=bq, block_kv=bk))
+                                    block_q=bq, block_kv=bk,
+                                    interpret=True))
     gold = np.asarray(ref.ref_hfa_mxu(q, k, v, causal=causal, block_kv=bk))
     np.testing.assert_allclose(out, gold, atol=1e-6)
 
@@ -58,7 +60,7 @@ def test_hfa_kernel_accuracy_vs_exact():
     q = _rand((2, 128, 64), jnp.bfloat16, 7)
     k = _rand((2, 256, 64), jnp.bfloat16, 8)
     v = _rand((2, 256, 64), jnp.bfloat16, 9)
-    out = np.asarray(hfa.hfa_pallas(q, k, v, causal=True))
+    out = np.asarray(hfa.hfa_pallas(q, k, v, causal=True, interpret=True))
     gold = np.asarray(ref.ref_fa2(q, k, v, causal=True))
     assert np.isfinite(out).all()
     assert np.abs(out - gold).mean() < 0.02  # quantized-exp regime
@@ -71,7 +73,7 @@ def test_hfa_datapath_kernel_bit_exact_vs_emulation():
     v = _rand((2, 32, 32), jnp.bfloat16, 12)
     for causal in (False, True):
         out = np.asarray(hfa_datapath.hfa_datapath_pallas(
-            q, k, v, causal=causal).astype(jnp.float32))
+            q, k, v, causal=causal, interpret=True).astype(jnp.float32))
         gold = np.asarray(ref.ref_hfa_datapath(q, k, v, causal=causal)
                           .astype(jnp.float32))
         assert np.array_equal(out, gold)
@@ -83,7 +85,8 @@ def test_decode_partial_vs_oracle(use_hfa, g, s, d):
     q = _rand((3, g, d), jnp.bfloat16, 13)
     k = _rand((3, s, d), jnp.bfloat16, 14)
     v = _rand((3, s, d), jnp.bfloat16, 15)
-    o, m, l = decode.decode_partial_pallas(q, k, v, use_hfa=use_hfa)
+    o, m, l = decode.decode_partial_pallas(q, k, v, use_hfa=use_hfa,
+                                           interpret=True)
     og, mg, lg = ref.ref_decode_partial(q, k, v, use_hfa=use_hfa)
     np.testing.assert_allclose(np.asarray(o), np.asarray(og), atol=2e-5)
     np.testing.assert_allclose(np.asarray(m), np.asarray(mg), atol=0)
@@ -101,7 +104,7 @@ def test_decode_split_merge_equals_full(parts, use_hfa):
     span = s // parts
     triplets = [decode.decode_partial_pallas(
         q, k[:, i * span:(i + 1) * span], v[:, i * span:(i + 1) * span],
-        use_hfa=use_hfa) for i in range(parts)]
+        use_hfa=use_hfa, interpret=True) for i in range(parts)]
     om, mm, lm = decode.merge_partials(
         jnp.stack([t[0] for t in triplets]),
         jnp.stack([t[1] for t in triplets]),
@@ -117,7 +120,8 @@ def test_decode_kv_len_masking():
     q = _rand((2, g, d), jnp.bfloat16, 19)
     k = _rand((2, s, d), jnp.bfloat16, 20)
     v = _rand((2, s, d), jnp.bfloat16, 21)
-    o, m, l = decode.decode_partial_pallas(q, k, v, kv_len=100)
+    o, m, l = decode.decode_partial_pallas(q, k, v, kv_len=100,
+                                           interpret=True)
     got = np.asarray(decode.finalize_decode(jnp.asarray(o), jnp.asarray(l)))
     gold = np.asarray(cref.exact_attention(q, k[:, :100], v[:, :100]))
     np.testing.assert_allclose(got, gold, atol=2e-5)
@@ -154,7 +158,8 @@ def test_fa2_forward_lse_residual():
     q = _rand((2, 128, 64), jnp.bfloat16, 33)
     k = _rand((2, 256, 64), jnp.bfloat16, 34)
     v = _rand((2, 256, 64), jnp.bfloat16, 35)
-    out, lse = fa2.fa2_pallas(q, k, v, causal=True, return_lse=True)
+    out, lse = fa2.fa2_pallas(q, k, v, causal=True, return_lse=True,
+                              interpret=True)
     s = np.einsum("bqd,bkd->bqk", np.asarray(q, np.float32),
                   np.asarray(k, np.float32)) / 8.0
     mask = np.tril(np.ones((128, 256), bool), k=128)
@@ -197,8 +202,8 @@ def _verify_setup(seed, *, b=2, hkv=2, g=4, d=64, page=8, pages_each=3,
     from repro.kernels import paged_prefill as paged_pf
     rng = np.random.default_rng(seed)
     num_pages = b * pages_each + 2
-    kp = _rand((num_pages, page, hkv, d), jnp.float32, seed + 1)
-    vp = _rand((num_pages, page, hkv, d), jnp.float32, seed + 2)
+    kp = _rand((num_pages, hkv, page, d), jnp.float32, seed + 1)
+    vp = _rand((num_pages, hkv, page, d), jnp.float32, seed + 2)
     pt = jnp.asarray(rng.permutation(num_pages)[:b * pages_each]
                      .reshape(b, pages_each).astype(np.int32))
     sl = jnp.asarray(rng.integers(1, pages_each * page - kw + 1, b)
@@ -208,13 +213,33 @@ def _verify_setup(seed, *, b=2, hkv=2, g=4, d=64, page=8, pages_each=3,
     return q, kp, vp, pt, sl, cl
 
 
+def _score_order_bound(q, k_dense, scale):
+    """Largest difference two f32 evaluations of the scores ``scale * q
+    . k`` may show when they sum the d products in different orders:
+    Higham's bound gamma_d * sum_i |q_i k_i| (gamma_d ~ d * eps32),
+    taken over every (query row, key).  The running max ``m`` is a
+    score, so two kernels that tile the dot differently may disagree on
+    ``m`` by up to this much - and no more."""
+    d = q.shape[-1]
+    gamma = d * np.finfo(np.float32).eps / (1 - d * np.finfo(np.float32)
+                                            .eps)
+    qa = np.abs(np.asarray(q, np.float32))        # (B, Hkv, G, K, d)
+    ka = np.abs(np.asarray(k_dense, np.float32))  # (B, S, Hkv, d)
+    return float(gamma * scale * np.einsum("bhgld,bshd->bhgls", qa,
+                                           ka).max())
+
+
 @pytest.mark.parametrize("d", [64, 128])
 @pytest.mark.parametrize("g", [1, 4])
 def test_paged_verify_k1_triplet_parity_matrix(d, g):
-    """Golden-parity matrix over head_dim and GQA group size: with one
-    verify column the paged_verify kernel, the paged_decode kernel, the
-    dense decode kernel, and the jnp triplet oracle must emit the same
-    (m, l, o~) triplets (fp32 tolerance) on ragged seq_lens."""
+    """Golden-parity matrix over head_dim and GQA group size, on ragged
+    seq_lens.  With one verify column the paged_verify kernel must be
+    the paged_decode kernel bit for bit - speculative decode is token-
+    exact because a K = 1 verify step computes what a decode step
+    does.  The dense decode kernel and the jnp triplet oracle sum the
+    score dot in another order, so their running max may differ by the
+    f32 dot-order bound (:func:`_score_order_bound`) and their l / o~
+    by the fp32 tolerances below."""
     from repro.kernels import paged_decode as paged
     from repro.kernels import paged_verify as paged_ver
     q, kp, vp, pt, sl, cl = _verify_setup(50 + d + g, d=d, g=g, kw=1)
@@ -223,28 +248,28 @@ def test_paged_verify_k1_triplet_parity_matrix(d, g):
         q, kp, vp, pt, sl, cl, interpret=True)
     od, md, ld = paged.paged_decode_partial_pallas(
         q[:, :, :, 0, :], kp, vp, pt, kvl, interpret=True)
-    np.testing.assert_allclose(np.asarray(mv[..., 0]), np.asarray(md),
-                               atol=0)
-    np.testing.assert_allclose(np.asarray(lv[..., 0]), np.asarray(ld),
-                               atol=1e-5)
-    np.testing.assert_allclose(np.asarray(ov[:, :, :, 0]), np.asarray(od),
-                               atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(mv[..., 0]), np.asarray(md))
+    np.testing.assert_array_equal(np.asarray(lv[..., 0]), np.asarray(ld))
+    np.testing.assert_array_equal(np.asarray(ov[:, :, :, 0]),
+                                  np.asarray(od))
+    k_dense = paged.gather_pages(kp, pt)
+    v_dense = paged.gather_pages(vp, pt)
+    m_tol = _score_order_bound(q, k_dense, 1.0 / d ** 0.5)
     # jnp triplet oracle (order-free softmax pieces)
     orf, mrf, lrf = paged_ver.paged_verify_partial_ref(q, kp, vp, pt, sl,
                                                        cl)
-    np.testing.assert_allclose(np.asarray(mv), np.asarray(mrf), atol=0)
+    np.testing.assert_allclose(np.asarray(mv), np.asarray(mrf), rtol=0,
+                               atol=m_tol)
     np.testing.assert_allclose(np.asarray(lv), np.asarray(lrf), atol=1e-4)
     np.testing.assert_allclose(np.asarray(ov), np.asarray(orf), atol=1e-3)
     # dense decode kernel on the gathered contiguous view, row by row
-    k_dense = paged.gather_pages(kp, pt)
-    v_dense = paged.gather_pages(vp, pt)
     for i in range(q.shape[0]):
         o3, m3, l3 = decode.decode_partial_pallas(
             q[i, :, :, 0, :], jnp.swapaxes(k_dense[i], 0, 1),
             jnp.swapaxes(v_dense[i], 0, 1), block_kv=8,
-            kv_len=int(kvl[i]))
+            kv_len=int(kvl[i]), interpret=True)
         np.testing.assert_allclose(np.asarray(mv[i, :, :, 0]),
-                                   np.asarray(m3), atol=0)
+                                   np.asarray(m3), rtol=0, atol=m_tol)
         np.testing.assert_allclose(np.asarray(lv[i, :, :, 0]),
                                    np.asarray(l3), atol=1e-5)
         np.testing.assert_allclose(np.asarray(ov[i, :, :, 0]),
@@ -329,8 +354,8 @@ def _cow_tables(seed, *, b=2, hkv=2, g=2, d=64, page=8, pages_each=3,
     from repro.kernels import paged_prefill as paged_pf
     rng = np.random.default_rng(seed)
     num_pages = 2 * pages_each + 2               # room for the copies
-    kp = _rand((num_pages, page, hkv, d), jnp.float32, seed + 1)
-    vp = _rand((num_pages, page, hkv, d), jnp.float32, seed + 2)
+    kp = _rand((num_pages, hkv, page, d), jnp.float32, seed + 1)
+    vp = _rand((num_pages, hkv, page, d), jnp.float32, seed + 2)
     src = rng.permutation(pages_each).astype(np.int32)       # slot 0 pages
     dst = (pages_each + rng.permutation(pages_each)).astype(np.int32)
     kp = paged_pf.copy_pages(kp, jnp.asarray(src), jnp.asarray(dst))
@@ -524,8 +549,8 @@ def test_paged_codec_cow_fork_and_rollback(name, impl):
     rng = np.random.default_rng(501)
     b, hkv, h, d, page, pages_each, kw = 2, 2, 4, 64, 8, 3, 2
     num_pages = 2 * pages_each + 2
-    kp = _rand((num_pages, page, hkv, d), jnp.float32, 502)
-    vp = _rand((num_pages, page, hkv, d), jnp.float32, 503)
+    kp = _rand((num_pages, hkv, page, d), jnp.float32, 502)
+    vp = _rand((num_pages, hkv, page, d), jnp.float32, 503)
     c = page_codec.get_codec(name)
     kd, ks = c.encode(kp)
     vd, vs = c.encode(vp)
@@ -558,23 +583,24 @@ def test_paged_codec_cow_fork_and_rollback(name, impl):
     # Rollback half: trash every encoded row (and scale) at positions
     # >= sl + kw; the reads above are bounded by seq/chunk lens, so the
     # outputs must not move by a single bit.
-    keep = np.zeros(kd.shape[:2], bool)           # (P, row) rows read
+    keep = np.zeros((num_pages, page), bool)      # (P, row) rows read
     mat_np = np.asarray(mat)
     for i in range(b):
         for pos in range(int(sl[i]) + kw):
             keep[mat_np[i, pos // page], pos % page] = True
+    kp_i, row_i = np.nonzero(keep)                # pools: (P, Hkv, row, .)
     jr = np.random.default_rng(505)
     pools = {"k": np.array(kd), "v": np.array(vd)}
     for key, orig in (("k", kd), ("v", vd)):
         a = pools[key]
         a[...] = jr.integers(1, 120, a.shape).astype(a.dtype)
-        a[keep] = np.asarray(orig)[keep]
+        a[kp_i, :, row_i] = np.asarray(orig)[kp_i, :, row_i]
     ksx, vsx = ks, vs
     if ks is not None:
         ksx, vsx = np.array(ks), np.array(vs)
         for a, orig in ((ksx, ks), (vsx, vs)):
             a[...] = jr.standard_normal(a.shape).astype(a.dtype)
-            a[keep] = np.asarray(orig)[keep]
+            a[kp_i, :, row_i] = np.asarray(orig)[kp_i, :, row_i]
     ck2 = dict(impl=impl, codec=c, k_scales=None if ksx is None
                else jnp.asarray(ksx),
                v_scales=None if vsx is None else jnp.asarray(vsx),

@@ -32,8 +32,8 @@ def _paged_setup(seed, *, b=3, hkv=2, g=4, d=32, page=16, pages_each=4,
     rng = np.random.default_rng(seed)
     num_pages = b * pages_each + extra_pages
     q = _rand((b, hkv, g, d), seed + 1, dtype)
-    k_pages = _rand((num_pages, page, hkv, d), seed + 2, dtype)
-    v_pages = _rand((num_pages, page, hkv, d), seed + 3, dtype)
+    k_pages = _rand((num_pages, hkv, page, d), seed + 2, dtype)
+    v_pages = _rand((num_pages, hkv, page, d), seed + 3, dtype)
     perm = rng.permutation(num_pages)[:b * pages_each]
     page_table = jnp.asarray(perm.reshape(b, pages_each).astype(np.int32))
     kv_lens = jnp.asarray(
@@ -60,7 +60,7 @@ def test_paged_kernel_matches_dense_kernel_float(seed):
         od, md, ld = decode.decode_partial_pallas(
             q[i], jnp.swapaxes(k_dense[i], 0, 1),
             jnp.swapaxes(v_dense[i], 0, 1),
-            block_kv=16, kv_len=int(kvl[i]))
+            block_kv=16, kv_len=int(kvl[i]), interpret=True)
         gold = np.asarray(decode.finalize_decode(od, ld))
         np.testing.assert_allclose(out[i], gold, atol=1e-5)
 
@@ -88,7 +88,7 @@ def test_paged_kernel_hfa_error_envelope(seed):
         od, md, ld = decode.decode_partial_pallas(
             q[i], jnp.swapaxes(k_dense[i], 0, 1),
             jnp.swapaxes(v_dense[i], 0, 1),
-            block_kv=16, kv_len=kvl_i, use_hfa=True)
+            block_kv=16, kv_len=kvl_i, use_hfa=True, interpret=True)
         dense_hfa = np.asarray(decode.finalize_decode(od, ld, use_hfa=True))
         err_paged = np.abs(out[i] - gold).max()
         err_dense = np.abs(dense_hfa - gold).max()
@@ -143,8 +143,8 @@ def test_ops_paged_matches_dense_decode():
 # ------------------------------------------------------ page cache ops
 def test_append_and_prefill_write_roundtrip():
     page, hkv, d = 8, 2, 16
-    kp = jnp.zeros((6, page, hkv, d))
-    vp = jnp.zeros((6, page, hkv, d))
+    kp = jnp.zeros((6, hkv, page, d))
+    vp = jnp.zeros((6, hkv, page, d))
     pt = jnp.asarray(np.array([[4, 1, 3], [5, 0, 2]], np.int32))
     k_new = _rand((2, 11, hkv, d), 21)
     v_new = _rand((2, 11, hkv, d), 22)
@@ -444,8 +444,8 @@ def _chunk_setup(seed, *, b=2, hkv=2, g=2, d=16, page=4, pages_each=6,
     full K/V for the oracle."""
     rng = np.random.default_rng(seed)
     num_pages = b * pages_each + 2
-    kp = jnp.zeros((num_pages, page, hkv, d), jnp.float32)
-    vp = jnp.zeros((num_pages, page, hkv, d), jnp.float32)
+    kp = jnp.zeros((num_pages, hkv, page, d), jnp.float32)
+    vp = jnp.zeros((num_pages, hkv, page, d), jnp.float32)
     pt = jnp.asarray(rng.permutation(num_pages)[:b * pages_each]
                      .reshape(b, pages_each).astype(np.int32))
     start = np.asarray(hist, np.int32)
@@ -491,7 +491,7 @@ def test_paged_prefill_kernel_matches_oracle(seed):
     dense softmax oracle."""
     q, kp, vp, pt, start, cl, k_full, v_full = _chunk_setup(seed)
     b, lmax, h, d = q.shape
-    hkv = kp.shape[2]
+    hkv = kp.shape[1]
     g = h // hkv
     out_jnp = np.asarray(ops.paged_prefill_attention(
         q, kp, vp, pt, jnp.asarray(start), jnp.asarray(cl), impl="fa2"))
@@ -520,7 +520,7 @@ def test_paged_prefill_kernel_hfa_rowwise_matches_decode_kernel():
     must not perturb the quantized numerics."""
     q, kp, vp, pt, start, cl, _, _ = _chunk_setup(43)
     b, lmax, h, d = q.shape
-    hkv = kp.shape[2]
+    hkv = kp.shape[1]
     g = h // hkv
     qg = jnp.swapaxes(q, 1, 2).reshape(b, hkv, g, lmax, d)
     o, m, l = paged_pf.paged_prefill_partial_pallas(
@@ -541,8 +541,16 @@ def test_paged_prefill_kernel_hfa_rowwise_matches_decode_kernel():
 @pytest.mark.parametrize("attn_impl", ["fa2", "hfa"])
 def test_model_chunked_prefill_matches_dense(qwen_smoke, attn_impl):
     """paged_prefill in two chunks (the second at pos > 0) must agree
-    with the dense whole-prompt prefill: same last logits and same
-    subsequent decode logits."""
+    with a whole-prompt prefill: same last logits and same subsequent
+    decode logits.
+
+    The reference must quantize in the same order as the rail under
+    test.  On fa2 that is the dense contiguous-cache model (exact
+    softmax math).  On hfa the dense model's prefill runs the
+    bit-accurate ``core_hfa`` emulation, a different H-FA algorithm, so
+    the reference is the paged rail itself run as one whole-prompt
+    chunk over contiguous pages: full-row FIX16 softmax per query, the
+    order the chunked path must reproduce."""
     import dataclasses
     cfg, model, params = qwen_smoke
     if attn_impl != cfg.attn_impl:
@@ -553,14 +561,23 @@ def test_model_chunked_prefill_matches_dense(qwen_smoke, attn_impl):
     b, l, cut = 2, 7, 4
     toks = jnp.asarray(rng.integers(1, cfg.vocab_size, (b, l)), jnp.int32)
     nxt = jnp.asarray(rng.integers(1, cfg.vocab_size, (b, 1)), jnp.int32)
+    zeros = jnp.zeros((b,), jnp.int32)
+    sl = jnp.full((b,), l, jnp.int32)
 
-    cache = model.init_cache(params, b, 32)
-    lg_d, cache = model.prefill(params, cache, toks)
-    lg_d2, _ = model.decode_step(params, cache, nxt)
+    if attn_impl == "hfa":
+        layers = model.init_paged_cache(num_pages=8, page_size=4)
+        pt_ref = jnp.asarray(np.array([[0, 1, 2], [3, 4, 5]], np.int32))
+        lg_d, layers = model.paged_prefill(
+            params, layers, toks, pt_ref,
+            last_pos=jnp.full((b,), l - 1, jnp.int32), start_pos=zeros)
+        lg_d2, _ = model.paged_decode_step(params, layers, nxt, pt_ref, sl)
+    else:
+        cache = model.init_cache(params, b, 32)
+        lg_d, cache = model.prefill(params, cache, toks)
+        lg_d2, _ = model.decode_step(params, cache, nxt)
 
     layers = model.init_paged_cache(num_pages=8, page_size=4)
     pt = jnp.asarray(np.array([[3, 5, 1], [2, 6, 0]], np.int32))
-    zeros = jnp.zeros((b,), jnp.int32)
     _, layers = model.paged_prefill(
         params, layers, toks[:, :cut], pt,
         last_pos=jnp.full((b,), cut - 1, jnp.int32), start_pos=zeros)
@@ -568,22 +585,55 @@ def test_model_chunked_prefill_matches_dense(qwen_smoke, attn_impl):
         params, layers, toks[:, cut:], pt,
         last_pos=jnp.full((b,), l - cut - 1, jnp.int32),
         start_pos=jnp.full((b,), cut, jnp.int32))
-    sl = jnp.full((b,), l, jnp.int32)
     lg_p2, _ = model.paged_decode_step(params, layers, nxt, pt, sl)
 
-    # fa2 paths share exact-softmax math.  The H-FA chunked path applies
-    # the FIX16 quantization in a different accumulation order than the
-    # dense emulation, so logits agree only within the quantization
-    # envelope (amplified by wo + lm_head); greedy argmax must hold.
-    tol = 5e-1 if attn_impl == "hfa" else 1e-4
     np.testing.assert_allclose(np.asarray(lg_p[:, -1:]), np.asarray(lg_d),
-                               atol=tol)
+                               atol=1e-4)
     np.testing.assert_allclose(np.asarray(lg_p2), np.asarray(lg_d2),
-                               atol=tol)
+                               atol=1e-4)
     assert np.array_equal(np.argmax(np.asarray(lg_p[:, -1:]), -1),
                           np.argmax(np.asarray(lg_d), -1))
     assert np.array_equal(np.argmax(np.asarray(lg_p2), -1),
                           np.argmax(np.asarray(lg_d2), -1))
+
+
+def test_engine_prompt_logits_match_dense_reference(qwen_smoke):
+    """ServingEngine.prompt_logits (the engine's own jitted prefill on a
+    scratch slot) equals a dense exact-softmax forward of the same
+    weights, and hands the slot and its pages back."""
+    import dataclasses
+    from repro.models.model import build_model
+    cfg, model, params = qwen_smoke
+    eng = ServingEngine(model, params, max_batch=2, page_size=8,
+                        max_seq=64, prefill_budget=32)
+    rng = np.random.default_rng(31)
+    prompt = rng.integers(1, cfg.vocab_size, 13).tolist()
+    got = eng.prompt_logits(prompt)
+    exact = build_model(dataclasses.replace(cfg, attn_impl="exact"))
+    ref, _ = exact.apply(params, {"tokens": jnp.asarray([prompt])},
+                         train=False)
+    np.testing.assert_allclose(got, np.asarray(ref[0, -1], np.float32),
+                               atol=1e-4)
+    eng.cache.check_invariants()
+    assert eng.cache.available_page_count == eng.cache.num_pages
+    assert eng.cache.free_slot_count == 2
+
+
+def test_engine_refuses_unsupported_page_size_on_tpu(qwen_smoke,
+                                                     monkeypatch):
+    """On the TPU the paged kernels compile only for the page sizes in
+    TPU_PAGE_SIZES; any other is refused at construction, not in the
+    compiler at the first step.  Off the TPU every size is served."""
+    from repro.kernels import paged_decode as paged_k
+    from repro.serving import engine as engine_mod
+    _, model, params = qwen_smoke
+    ServingEngine(model, params, max_batch=1, page_size=4, max_seq=16)
+    monkeypatch.setattr(engine_mod.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="page_size 4"):
+        ServingEngine(model, params, max_batch=1, page_size=4, max_seq=16)
+    for page in paged_k.TPU_PAGE_SIZES:
+        ServingEngine(model, params, max_batch=1, page_size=page,
+                      max_seq=32)
 
 
 def test_engine_chunked_prefill_token_exact(qwen_smoke):
@@ -901,8 +951,8 @@ def test_engine_hfa_free_slot_no_nan():
     rng = np.random.default_rng(61)
     b, hkv, g, d, page, J = 3, 2, 2, 16, 4, 3
     num_pages = 10
-    kp = rng.standard_normal((num_pages, page, hkv, d)).astype(np.float32)
-    vp = rng.standard_normal((num_pages, page, hkv, d)).astype(np.float32)
+    kp = rng.standard_normal((num_pages, hkv, page, d)).astype(np.float32)
+    vp = rng.standard_normal((num_pages, hkv, page, d)).astype(np.float32)
     pt = np.array([[1, 2, 3], [0, 0, 0], [4, 5, 6]], np.int32)
     kvl = np.array([5, 0, 7], np.int32)          # slot 1 is free
     q = _rand((b, 1, hkv * g, d), 62)

@@ -44,9 +44,9 @@ def _pool_setup(seed=0, b=3, hkv=2, g=2, d=64, page=8, pages_per_seq=4):
     """Random pools + page tables with ragged lengths (slot 0 free)."""
     rng = np.random.default_rng(seed)
     num_pages = b * pages_per_seq
-    kp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)),
+    kp = jnp.asarray(rng.standard_normal((num_pages, hkv, page, d)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)),
+    vp = jnp.asarray(rng.standard_normal((num_pages, hkv, page, d)),
                      jnp.float32)
     pt = jnp.asarray(rng.permutation(num_pages).reshape(b, pages_per_seq)
                      .astype(np.int32))
@@ -117,9 +117,9 @@ def test_merge_partials_verify_page_splits(use_hfa):
     rng = np.random.default_rng(1)
     b, hkv, g, d, page, pps, kw = 2, 2, 2, 64, 8, 4, 3
     num_pages = b * pps
-    kp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)),
+    kp = jnp.asarray(rng.standard_normal((num_pages, hkv, page, d)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)),
+    vp = jnp.asarray(rng.standard_normal((num_pages, hkv, page, d)),
                      jnp.float32)
     pt = jnp.asarray(rng.permutation(num_pages).reshape(b, pps)
                      .astype(np.int32))
@@ -165,8 +165,8 @@ mesh = make_tp_mesh(2)
 rng = np.random.default_rng(0)
 b, hkv, g, d, page, pps = 3, 2, 2, 64, 8, 4
 num_pages = b * pps
-kp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)), jnp.float32)
-vp = jnp.asarray(rng.standard_normal((num_pages, page, hkv, d)), jnp.float32)
+kp = jnp.asarray(rng.standard_normal((num_pages, hkv, page, d)), jnp.float32)
+vp = jnp.asarray(rng.standard_normal((num_pages, hkv, page, d)), jnp.float32)
 pt = jnp.asarray(rng.permutation(num_pages).reshape(b, pps).astype(np.int32))
 sl = jnp.asarray([0, 13, 31], jnp.int32)
 q = jnp.asarray(rng.standard_normal((b, 1, hkv * g, d)), jnp.float32)
@@ -178,7 +178,7 @@ kp1, vp1 = paged_k.append_kv(kp, vp, kn, vn, pt, sl)
 kv_lens = jnp.where(sl > 0, sl + 1, 0)
 ref = ops.paged_decode_attention(q, kp1, vp1, pt, kv_lens, impl="fa2")
 
-sh = NamedSharding(mesh, P(None, None, "model", None))
+sh = NamedSharding(mesh, P(None, "model", None, None))
 pools = {"k_pages": jax.device_put(kp, sh), "v_pages": jax.device_put(vp, sh)}
 out, pools2 = jax.jit(lambda *a: collectives.shardmap_paged_attention(
     *a, mesh=mesh, mode="decode", impl="fa2"))(
@@ -430,6 +430,63 @@ for bad in ((0, 1), (1, 0)):
         raise SystemExit("expected ValueError")
     except ValueError:
         pass
+print("OK")
+""")
+    assert "OK" in out
+
+
+def test_router_replicas_each_on_own_device():
+    """serve_http's stack with --replicas 2 on a 2-device host: replica
+    i's params and pools live on device i (read from the arrays), both
+    replicas serve over HTTP, and concurrent greedy streams equal the
+    single-engine streams."""
+    out = _run("""
+import asyncio, jax, numpy as np
+from repro.configs import get_config
+from repro.launch.serve_http import build_server
+from repro.models.model import build_model
+from repro.serving.http import stream_generate
+
+cfg = get_config("qwen3-1.7b").reduced()
+model = build_model(cfg)
+params = model.init(jax.random.PRNGKey(0))
+rng = np.random.default_rng(3)
+prompts = [rng.integers(1, cfg.vocab_size, 12).tolist() for _ in range(2)]
+
+async def serve(replicas):
+    server, fes = build_server(model, params, batch=2, page_size=8,
+                               max_seq=64, prefill_budget=16,
+                               replicas=replicas)
+    await server.start()
+
+    async def one(p):
+        toks = []
+        async for kind, data in stream_generate(
+                server.host, server.port,
+                {"prompt": p, "max_new_tokens": 6}):
+            if kind == "token":
+                toks.append(data["token"])
+        return toks
+    try:
+        got = await asyncio.gather(*(one(p) for p in prompts))
+        for fe in fes:
+            await fe.drain()
+    finally:
+        await server.stop()
+        for fe in fes:
+            await fe.close()
+    return got, [fe.engine for fe in fes]
+
+one, _ = asyncio.run(serve(1))
+two, engines = asyncio.run(serve(2))
+assert one == two, (one, two)
+devs = jax.devices()
+for i, e in enumerate(engines):
+    for tree in (e.params, e.layers):
+        assert {d for x in jax.tree.leaves(tree) for d in x.devices()} \\
+            == {devs[i]}, i
+    assert e.stats["steps"] > 0, i
+    e.cache.check_invariants()
 print("OK")
 """)
     assert "OK" in out

@@ -1,0 +1,127 @@
+"""Compile-only checks of the paged serving kernels for a TPU v5e.
+
+Nothing runs: each test lowers a kernel at qwen3-1.7b widths (8 KV
+heads of d_head 128, GQA group 2, bf16 pools, page 16) against a
+*described* v5e:2x2 topology and compiles it with the TPU's compiler,
+which is installed here even without a chip.  What Mosaic refuses here
+(block shapes off the (8, 128) tiling, too much VMEM) it would refuse on
+the chip at the first serving step.
+
+The topology is described inside a module fixture, never at import: only
+one process may hold the TPU library, and every test worker imports
+this file.  Where it cannot be described the tests skip.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels import page_codec
+from repro.kernels import paged_decode
+from repro.kernels import paged_prefill
+from repro.kernels import paged_verify
+
+# qwen3-1.7b widths: batch 8, 8 KV heads, GQA group 2, d_head 128.
+B, HKV, G, D = 8, 8, 2, 128
+PAGE = 16
+PAGES_PER_SEQ = 16             # 256-token context per slot
+NUM_PAGES = B * PAGES_PER_SEQ
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU lib"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _pools(sh, codec, hkv, page=PAGE):
+    """Shape stand-ins of one layer's K/V pools (and scale sidecars)."""
+    c = page_codec.get_codec(codec)
+    shape = (NUM_PAGES, hkv, page, D)
+    data = jax.ShapeDtypeStruct(shape, c.storage_dtype(jnp.bfloat16),
+                                sharding=sh)
+    extra = {}
+    if c.has_scales:
+        s = jax.ShapeDtypeStruct(shape[:-1] + (1,), jnp.float32,
+                                 sharding=sh)
+        extra = {"k_scales": s, "v_scales": s}
+    return data, extra, (None if c.name == "fp" else c)
+
+
+def _i32(sh, *shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sh)
+
+
+def _compile_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("codec,use_hfa,hkv", [
+    ("fp", False, HKV), ("fp", True, HKV), ("int8", True, HKV),
+    ("log16", True, HKV), ("fp", True, HKV // 4)])
+def test_paged_decode_compiles(one_chip, codec, use_hfa, hkv):
+    """Decode on both rails, the int8 / log16 codecs, and the 2 local
+    heads of a TP-4 shard."""
+    pool, extra, c = _pools(one_chip, codec, hkv)
+    q = jax.ShapeDtypeStruct((B, hkv, G, D), jnp.bfloat16, sharding=one_chip)
+
+    def f(q, k, v, pt, kl, **sc):
+        return paged_decode.paged_decode_partial_pallas(
+            q, k, v, pt, kl, interpret=False, use_hfa=use_hfa, codec=c,
+            **sc)
+
+    text = _compile_text(lambda *a: f(*a[:5], **dict(zip(extra, a[5:]))),
+                         q, pool, pool, _i32(one_chip, B, PAGES_PER_SEQ),
+                         _i32(one_chip, B), *extra.values())
+    assert "tpu_custom_call" in text
+    assert "paged_flash_decode_partial" in text
+
+
+@pytest.mark.parametrize("use_hfa", [False, True])
+def test_paged_prefill_compiles(one_chip, use_hfa):
+    """A 128-token prefill chunk per slot, on both rails."""
+    pool, _, _ = _pools(one_chip, "fp", HKV)
+    q = jax.ShapeDtypeStruct((B, HKV, G, 128, D), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compile_text(
+        lambda q, k, v, pt, sp, kl: paged_prefill.paged_prefill_partial_pallas(
+            q, k, v, pt, sp, kl, interpret=False, use_hfa=use_hfa),
+        q, pool, pool, _i32(one_chip, B, PAGES_PER_SEQ), _i32(one_chip, B),
+        _i32(one_chip, B))
+    assert "paged_prefill_partial" in text
+
+
+@pytest.mark.parametrize("use_hfa", [False, True])
+def test_paged_verify_compiles(one_chip, use_hfa):
+    """A speculative verify step of K = 5 positions, on both rails."""
+    pool, _, _ = _pools(one_chip, "fp", HKV)
+    q = jax.ShapeDtypeStruct((B, HKV, G, 5, D), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compile_text(
+        lambda q, k, v, pt, sl, cl: paged_verify.paged_verify_partial_pallas(
+            q, k, v, pt, sl, cl, interpret=False, use_hfa=use_hfa),
+        q, pool, pool, _i32(one_chip, B, PAGES_PER_SEQ), _i32(one_chip, B),
+        _i32(one_chip, B))
+    assert "paged_verify_partial" in text
+
+
+@pytest.mark.parametrize("page", paged_decode.TPU_PAGE_SIZES)
+def test_supported_page_sizes_compile(one_chip, page):
+    """Every page size the engine admits on the TPU compiles (decode,
+    H-FA rail, bf16 pool)."""
+    pool = jax.ShapeDtypeStruct((NUM_PAGES, HKV, page, D), jnp.bfloat16,
+                                sharding=one_chip)
+    q = jax.ShapeDtypeStruct((B, HKV, G, D), jnp.bfloat16, sharding=one_chip)
+    text = _compile_text(
+        lambda q, k, v, pt, kl: paged_decode.paged_decode_partial_pallas(
+            q, k, v, pt, kl, interpret=False, use_hfa=True),
+        q, pool, pool, _i32(one_chip, B, 256 // page), _i32(one_chip, B))
+    assert "tpu_custom_call" in text

@@ -19,8 +19,9 @@ Checks, per scanned document:
   * relative markdown link targets -> must resolve from the doc's
     directory;
   * `--flag` tokens -> must be defined by some argparse entry point
-    (benchmarks/*.py, src/repro/launch/*.py, tools/*.py) or be on the
-    allowlist of external flags (XLA/pytest flags we merely quote).
+    (benchmarks/*.py, src/repro/launch/*.py, tools/*.py, chip_smoke.py)
+    or be on the allowlist of external flags (XLA/pytest flags we
+    merely quote).
 
 Usage: python tools/check_docs.py   (exit 0 = consistent)
 """
@@ -60,6 +61,7 @@ def _defined_flags() -> set[str]:
         full = os.path.join(REPO, d)
         scan += [os.path.join(full, f) for f in os.listdir(full)
                  if f.endswith(".py")]
+    scan.append(os.path.join(REPO, "chip_smoke.py"))
     for path in scan:
         with open(path, encoding="utf-8") as fh:
             flags.update(ARGPARSE_RE.findall(fh.read()))
