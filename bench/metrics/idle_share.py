@@ -1,0 +1,8 @@
+"""idle_share (device, device trace): the share of the traced window in
+which no operation ran on the chip, in %."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace["busy_s"] / run.trace["window_s"])
