@@ -65,8 +65,6 @@ ordering of mark_prefilled / rollback / register_pages below.
 """
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 import jax
@@ -78,7 +76,7 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels import page_codec
 from repro.kernels import paged_decode as paged_k
 from repro.kernels import paged_prefill as paged_pf_k
-from repro.serving import sampler
+from repro.serving import sampler, tracing
 from repro.serving.paged_cache import PagedKVCache
 from repro.serving.scheduler import (FinishedRequest, InvalidRequestError,
                                      PrefillChunk, Request, Scheduler)
@@ -354,8 +352,8 @@ class ServingEngine:
                 f"need 1 <= adaptive_floor <= adaptive_ceiling, got "
                 f"{self.adaptive_floor}..{self.adaptive_ceiling}")
         # EMA of measured prefill throughput (tokens/sec of wall time in
-        # _run_chunks) - the rate adaptive_prefill_budget converts SLA
-        # headroom seconds into a token budget with.
+        # the engine.prefill.* phases) - the rate adaptive_prefill_budget
+        # converts SLA headroom seconds into a token budget with.
         self._prefill_rate = 0.0
         self.prefix_caching = prefix_caching
         self.spec_k = spec_k
@@ -409,6 +407,9 @@ class ServingEngine:
                       "stream_overflows": 0}   # bounded per-stream
         #                                        queues hitting capacity
         #                                        (stalled readers)
+        # Wall, thread-CPU seconds and passes of every step and frontend
+        # phase (repro.serving.tracing), beside the counters above.
+        self.phases = tracing.new_totals()
         (self._prefill, self._decode, self._verify, self._copy,
          self._sample, self._topk,
          self._prefill_lp) = _serving_jits(model, mesh, self.kv_codec)
@@ -534,16 +535,48 @@ class ServingEngine:
         self.stats["adaptive_budget_last"] = budget
         return budget
 
+    def _phase(self, name: str) -> tracing.Phase:
+        """A phase of the step in progress (see repro.serving.tracing)."""
+        return tracing.Phase(self.phases, name, step=self.stats["steps"])
+
     def step(self) -> list[FinishedRequest]:
         """One token-budget step: continue/admit prefill chunks, run one
         batched (speculative) decode over every decoding slot; returns
-        the requests that finished during this step."""
+        the requests that finished during this step.  Its work is tiled
+        by the ``engine.*`` phases of :mod:`repro.serving.tracing`."""
         finished: list[FinishedRequest] = []
         # Decoding slots claim their next page BEFORE prefill work is
         # scheduled - otherwise a prompt chunk can grab the last free
         # pages and evict an in-flight decode into a costly replay.
-        self._capacity_pass()
+        with self._phase("engine.capacity"):
+            self._capacity_pass()
 
+        with self._phase("engine.plan"):
+            chunks = self._plan_prefill()
+        with self._phase("engine.cow"):
+            self._apply_pending_copies()
+        if chunks:
+            wall = tracing.total_wall_s(self.phases, tracing.PREFILL_PHASES)
+            self._run_chunks(chunks, finished)
+            wall = tracing.total_wall_s(self.phases,
+                                        tracing.PREFILL_PHASES) - wall
+            n_tok = sum(len(ck.tokens) for ck in chunks)
+            if wall > 0.0 and n_tok:
+                rate = n_tok / wall
+                self._prefill_rate = rate if self._prefill_rate == 0.0 \
+                    else 0.8 * self._prefill_rate + 0.2 * rate
+        # Second (idempotent) capacity pass: slots that finished their
+        # prefill this step also append a token below, and a prompt
+        # ending exactly on a page boundary needs its next page before
+        # the decode scatter.
+        with self._phase("engine.capacity"):
+            self._capacity_pass()
+        self._run_decode(finished)
+        self.stats["steps"] += 1
+        return finished
+
+    def _plan_prefill(self) -> list[PrefillChunk]:
+        """This step's prefill chunks under its token budget."""
         budget = self._step_budget()
         chunks, reused = self.sched.schedule_prefill(budget)
         if not chunks and not self.sched.decoding_slots() \
@@ -559,25 +592,7 @@ class ServingEngine:
                 chunks, r2 = self.sched.schedule_prefill(budget)
                 reused += r2
         self.stats["cached_prefill_tokens"] += reused
-
-        self._apply_pending_copies()
-        t0 = time.perf_counter()
-        self._run_chunks(chunks, finished)
-        if chunks:
-            dt = time.perf_counter() - t0
-            n_tok = sum(len(ck.tokens) for ck in chunks)
-            if dt > 0.0 and n_tok:
-                rate = n_tok / dt
-                self._prefill_rate = rate if self._prefill_rate == 0.0 \
-                    else 0.8 * self._prefill_rate + 0.2 * rate
-        # Second (idempotent) capacity pass: slots that finished their
-        # prefill this step also append a token below, and a prompt
-        # ending exactly on a page boundary needs its next page before
-        # the decode scatter.
-        self._capacity_pass()
-        self._run_decode(finished)
-        self.stats["steps"] += 1
-        return finished
+        return chunks
 
     # ---------------------------------------------------------- capacity
     def _capacity_pass(self) -> None:
@@ -652,54 +667,63 @@ class ServingEngine:
         branches over ``fork`` (sharing every prompt page), and all
         first tokens - one per plain request, one per branch - are drawn
         in a single shared sampling call."""
-        for ck in chunks:
-            self._set_sampling(ck.slot)
-        groups: dict[int, list[PrefillChunk]] = {}
-        for ck in chunks:
-            lpad = -(-len(ck.tokens) // self.page_size) * self.page_size
-            groups.setdefault(lpad, []).append(ck)
+        with self._phase("engine.prefill.inputs"):
+            for ck in chunks:
+                self._set_sampling(ck.slot)
+            groups: dict[int, list[PrefillChunk]] = {}
+            for ck in chunks:
+                lpad = -(-len(ck.tokens) // self.page_size) * self.page_size
+                groups.setdefault(lpad, []).append(ck)
         for lpad, grp in sorted(groups.items()):
-            bsz = len(grp)
-            width = self._pow2_width(max(
-                self.cache.pages_for(ck.start + len(ck.tokens))
-                for ck in grp))
-            toks = np.zeros((bsz, lpad), np.int32)
-            rows = np.zeros((bsz, width), np.int32)
-            start = np.zeros((bsz,), np.int32)
-            last = np.zeros((bsz,), np.int32)
-            for i, ck in enumerate(grp):
-                toks[i, :len(ck.tokens)] = ck.tokens
-                rows[i] = self.cache.page_table[ck.slot, :width]
-                start[i] = ck.start
-                last[i] = len(ck.tokens) - 1
-            # Any logprobs request in the batch routes the whole group
-            # through the prompt-logprobs prefill (full-position LM
-            # head); groups without one stay on the gathered fast path.
-            want_plp = any(self.sched.running[ck.slot].req.logprobs
-                           for ck in grp)
+            with self._phase("engine.prefill.inputs"):
+                bsz = len(grp)
+                width = self._pow2_width(max(
+                    self.cache.pages_for(ck.start + len(ck.tokens))
+                    for ck in grp))
+                toks = np.zeros((bsz, lpad), np.int32)
+                rows = np.zeros((bsz, width), np.int32)
+                start = np.zeros((bsz,), np.int32)
+                last = np.zeros((bsz,), np.int32)
+                for i, ck in enumerate(grp):
+                    toks[i, :len(ck.tokens)] = ck.tokens
+                    rows[i] = self.cache.page_table[ck.slot, :width]
+                    start[i] = ck.start
+                    last[i] = len(ck.tokens) - 1
+                args = (jnp.asarray(toks), jnp.asarray(rows),
+                        jnp.asarray(start), jnp.asarray(last))
+                # Any logprobs request in the batch routes the whole
+                # group through the prompt-logprobs prefill
+                # (full-position LM head); groups without one stay on
+                # the gathered fast path.
+                want_plp = any(self.sched.running[ck.slot].req.logprobs
+                               for ck in grp)
+                if want_plp:
+                    args += (jnp.asarray(self._prompt_targets(grp, lpad)),)
+            with self._phase("engine.prefill.dispatch"):
+                if want_plp:
+                    logits, plp, self.layers = self._prefill_lp(
+                        self.params, self.layers, *args)
+                else:
+                    logits, self.layers = self._prefill(
+                        self.params, self.layers, *args)
             if want_plp:
-                logits, plp, self.layers = self._prefill_lp(
-                    self.params, self.layers, jnp.asarray(toks),
-                    jnp.asarray(rows), jnp.asarray(start),
-                    jnp.asarray(last), jnp.asarray(
-                        self._prompt_targets(grp, lpad)))
-                self._record_prompt_lps(grp, np.asarray(plp))
-            else:
-                logits, self.layers = self._prefill(
-                    self.params, self.layers, jnp.asarray(toks),
-                    jnp.asarray(rows), jnp.asarray(start), jnp.asarray(last))
-            self.stats["prefills"] += 1
-            self._count_triplets(bsz, lpad)
-            finals = []
-            for i, ck in enumerate(grp):
-                self.stats["prefill_chunks"] += 1
-                self.stats["prefill_tokens"] += len(ck.tokens)
-                self.sched.complete_chunk(ck)
-                if self.prefix_caching:
-                    self.cache.register_pages(
-                        ck.slot, self.sched.running[ck.slot].tokens())
-                if ck.is_final:
-                    finals.append((i, ck.slot))
+                with self._phase("engine.prefill.sync"):
+                    plp = np.asarray(plp)
+            with self._phase("engine.prefill.commit"):
+                if want_plp:
+                    self._record_prompt_lps(grp, plp)
+                self.stats["prefills"] += 1
+                self._count_triplets(bsz, lpad)
+                finals = []
+                for i, ck in enumerate(grp):
+                    self.stats["prefill_chunks"] += 1
+                    self.stats["prefill_tokens"] += len(ck.tokens)
+                    self.sched.complete_chunk(ck)
+                    if self.prefix_caching:
+                        self.cache.register_pages(
+                            ck.slot, self.sched.running[ck.slot].tokens())
+                    if ck.is_final:
+                        finals.append((i, ck.slot))
             if finals:
                 self._finish_prefills(logits, finals, finished)
 
@@ -742,23 +766,24 @@ class ServingEngine:
         expansion."""
         rows: list[tuple[int, int]] = []     # (logits row, slot)
         beams: list[tuple[int, int]] = []
-        for i, slot in finals:
-            st = self.sched.running[slot]
-            if st.group is None:
-                self._rebuild_presence(slot)
-                rows.append((i, slot))
-            elif st.group.beam:
-                self.stats["groups"] += 1
-                beams.append((i, slot))
-            else:
-                self.stats["groups"] += 1
-                base = st.req.sampling or sampler.GREEDY
-                branches = self.sched.fan_out(slot)
-                self.stats["forks"] += len(branches) - 1
-                for bslot, b in branches:
-                    self._set_branch_sampling(bslot, base, b)
-                    self._rebuild_presence(bslot)
-                    rows.append((i, bslot))
+        with self._phase("engine.prefill.commit"):
+            for i, slot in finals:
+                st = self.sched.running[slot]
+                if st.group is None:
+                    self._rebuild_presence(slot)
+                    rows.append((i, slot))
+                elif st.group.beam:
+                    self.stats["groups"] += 1
+                    beams.append((i, slot))
+                else:
+                    self.stats["groups"] += 1
+                    base = st.req.sampling or sampler.GREEDY
+                    branches = self.sched.fan_out(slot)
+                    self.stats["forks"] += len(branches) - 1
+                    for bslot, b in branches:
+                        self._set_branch_sampling(bslot, base, b)
+                        self._rebuild_presence(bslot)
+                        rows.append((i, bslot))
         if rows:
             self._sample_first_tokens(logits, rows, finished)
         for i, slot in beams:
@@ -769,62 +794,70 @@ class ServingEngine:
         ``rows[j] = (logits row, slot)`` at the slot's stream position
         under the slot's (branch) seed - the same code path a decode
         step's sampler uses, padded to a power-of-two row count."""
-        n = 1
-        while n < len(rows):
-            n *= 2
-        src = np.zeros((n,), np.int32)
-        slots = np.zeros((n,), np.int64)
-        pos = np.zeros((n,), np.int32)
-        for j, (i, slot) in enumerate(rows):
-            src[j] = i
-            slots[j] = slot
-            # The sampled token's stream index is the prompt length plus
-            # any generated tokens replayed after a preemption - i.e.
-            # the stream length itself.
-            pos[j] = self.sched.running[slot].target
-        greedy = self._all_greedy(slot for _, slot in rows)
-        want_lp = self._want_logprobs()
-        pres = _NO_PRESENCE if greedy else self._presence[slots]
-        lrows = jnp.take(logits, jnp.asarray(src), axis=0)
-        toks, lps = self._sample(
-            lrows, jnp.asarray(pres), jnp.asarray(self._seed[slots]),
-            jnp.asarray(pos), jnp.asarray(self._temp[slots]),
-            jnp.asarray(self._top_k[slots]),
-            jnp.asarray(self._top_p[slots]),
-            jnp.asarray(self._rep_pen[slots]), greedy, want_lp)
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        for j, (i, slot) in enumerate(rows):
-            tok = int(toks[j])
-            self.stats["generated_tokens"] += 1
-            st = self.sched.running[slot]
-            status = self.sched.record_token(slot, tok)
-            st.cum_logprob += float(lps[j])
-            if st.req.logprobs:
-                st.token_logprobs.append(float(lps[j]))
-            self._presence[slot, tok] = True
-            if status != "running":
-                fr = self.sched.finish(slot, status)
-                if fr is not None:
-                    finished.append(fr)
+        with self._phase("engine.prefill.inputs"):
+            n = 1
+            while n < len(rows):
+                n *= 2
+            src = np.zeros((n,), np.int32)
+            slots = np.zeros((n,), np.int64)
+            pos = np.zeros((n,), np.int32)
+            for j, (i, slot) in enumerate(rows):
+                src[j] = i
+                slots[j] = slot
+                # The sampled token's stream index is the prompt length
+                # plus any generated tokens replayed after a preemption
+                # - i.e. the stream length itself.
+                pos[j] = self.sched.running[slot].target
+            greedy = self._all_greedy(slot for _, slot in rows)
+            want_lp = self._want_logprobs()
+            pres = _NO_PRESENCE if greedy else self._presence[slots]
+            args = (jnp.asarray(src), jnp.asarray(pres),
+                    jnp.asarray(self._seed[slots]), jnp.asarray(pos),
+                    jnp.asarray(self._temp[slots]),
+                    jnp.asarray(self._top_k[slots]),
+                    jnp.asarray(self._top_p[slots]),
+                    jnp.asarray(self._rep_pen[slots]))
+        with self._phase("engine.prefill.dispatch"):
+            lrows = jnp.take(logits, args[0], axis=0)
+            toks, lps = self._sample(lrows, *args[1:], greedy, want_lp)
+        with self._phase("engine.prefill.sync"):
+            toks = np.asarray(toks)
+            lps = np.asarray(lps)
+        with self._phase("engine.prefill.commit"):
+            for j, (i, slot) in enumerate(rows):
+                tok = int(toks[j])
+                self.stats["generated_tokens"] += 1
+                st = self.sched.running[slot]
+                status = self.sched.record_token(slot, tok)
+                st.cum_logprob += float(lps[j])
+                if st.req.logprobs:
+                    st.token_logprobs.append(float(lps[j]))
+                self._presence[slot, tok] = True
+                if status != "running":
+                    fr = self.sched.finish(slot, status)
+                    if fr is not None:
+                        finished.append(fr)
 
     def _expand_beam_root(self, logits, i, slot, finished):
         """First beam expansion: top-2*width (logprob, token) candidates
         from the prompt's last-position logits seed the beam."""
         group = self.sched.running[slot].group
-        vals, idx = self._topk(logits[i:i + 1], 2 * group.width)
-        cands = list(zip(np.asarray(idx)[0].tolist(),
-                         np.asarray(vals)[0].tolist()))
-        before_tok = self.sched.tokens_emitted
-        before_forks = self.sched.forks
-        fr = self.sched.fan_out_beam(slot, cands)
-        self.stats["generated_tokens"] += \
-            self.sched.tokens_emitted - before_tok
-        self.stats["forks"] += self.sched.forks - before_forks
-        if fr is not None:
-            finished.append(fr)
-        else:
-            self._reset_beam_slots(group)
+        with self._phase("engine.prefill.dispatch"):
+            vals, idx = self._topk(logits[i:i + 1], 2 * group.width)
+        with self._phase("engine.prefill.sync"):
+            cands = list(zip(np.asarray(idx)[0].tolist(),
+                             np.asarray(vals)[0].tolist()))
+        with self._phase("engine.prefill.commit"):
+            before_tok = self.sched.tokens_emitted
+            before_forks = self.sched.forks
+            fr = self.sched.fan_out_beam(slot, cands)
+            self.stats["generated_tokens"] += \
+                self.sched.tokens_emitted - before_tok
+            self.stats["forks"] += self.sched.forks - before_forks
+            if fr is not None:
+                finished.append(fr)
+            else:
+                self._reset_beam_slots(group)
 
     def _set_branch_sampling(self, slot: int, sp, branch: int) -> None:
         """Branch ``branch`` samples under ``branch_seed(seed, branch)``
@@ -864,6 +897,48 @@ class ServingEngine:
         ride along with a single carry column: their next tokens come
         from the per-group top-2k reorder after the call, never from
         the sampler."""
+        with self._phase("engine.plan"):
+            plan = self._plan_decode()
+        if plan is None:
+            return
+        steps, toks, dl, cl, width, beam_groups = plan
+        with self._phase("engine.cow"):
+            self._apply_pending_copies()
+        with self._phase("engine.decode.inputs"):
+            kw = toks.shape[1]
+            step_fn = self._decode if kw == 1 else self._verify
+            greedy = self._all_greedy(s.slot for s in steps)
+            beam_k = 2 * max((g.width for g in beam_groups.values()),
+                             default=0)
+            want_lp = self._want_logprobs()
+            args = (jnp.asarray(toks),
+                    jnp.asarray(self.cache.page_table[:, :width]),
+                    jnp.asarray(dl), jnp.asarray(cl),
+                    jnp.asarray(self._seed), jnp.asarray(self._temp),
+                    jnp.asarray(self._top_k), jnp.asarray(self._top_p),
+                    jnp.asarray(self._rep_pen),
+                    jnp.asarray(_NO_PRESENCE if greedy else self._presence))
+        with self._phase("engine.decode.dispatch"):
+            sampled, tkv, tki, lps, self.layers = step_fn(
+                self.params, self.layers, *args, greedy, beam_k, want_lp)
+        with self._phase("engine.decode.sync"):
+            sampled = np.asarray(sampled)
+            lps = np.asarray(lps)
+        with self._phase("engine.decode.commit"):
+            self.stats["decode_steps"] += 1
+            self.stats["decode_slot_steps"] += len(steps)
+            self._count_triplets(self.max_batch, kw)
+            self._commit_decode(steps, sampled, lps, finished)
+            if beam_groups:
+                self._reorder_beams(beam_groups, np.asarray(tkv),
+                                    np.asarray(tki), finished)
+
+    def _plan_decode(self):
+        """This step's decode work: the scheduler's decode steps (at
+        this step's draft count), each shrunk to the pages it can write,
+        as the (max_batch, k + 1) token matrix, the per-slot seq and
+        chunk lengths, the page-table width, and the live beam groups;
+        None when no slot decodes."""
         k = self.spec_k
         if self.auto_spec:
             # Draft-count auto-tune: spend draft compute proportional to
@@ -874,9 +949,8 @@ class ServingEngine:
         self.stats["spec_k_last"] = k
         steps = self.sched.schedule_decode(k)
         if not steps:
-            return
-        kw = k + 1
-        toks = np.zeros((self.max_batch, kw), np.int32)
+            return None
+        toks = np.zeros((self.max_batch, k + 1), np.int32)
         dl = np.zeros((self.max_batch,), np.int32)
         cl = np.zeros((self.max_batch,), np.int32)
         beam_groups: dict[int, object] = {}
@@ -901,26 +975,11 @@ class ServingEngine:
         width = self._pow2_width(max(
             self.cache.pages_for(int(dl[s.slot] + cl[s.slot]))
             for s in steps))
-        self._apply_pending_copies()
-        step_fn = self._decode if kw == 1 else self._verify
-        greedy = self._all_greedy(s.slot for s in steps)
-        beam_k = 2 * max((g.width for g in beam_groups.values()),
-                         default=0)
-        want_lp = self._want_logprobs()
-        sampled, tkv, tki, lps, self.layers = step_fn(
-            self.params, self.layers, jnp.asarray(toks),
-            jnp.asarray(self.cache.page_table[:, :width]),
-            jnp.asarray(dl), jnp.asarray(cl),
-            jnp.asarray(self._seed), jnp.asarray(self._temp),
-            jnp.asarray(self._top_k), jnp.asarray(self._top_p),
-            jnp.asarray(self._rep_pen),
-            jnp.asarray(_NO_PRESENCE if greedy else self._presence),
-            greedy, beam_k, want_lp)
-        sampled = np.asarray(sampled)
-        lps = np.asarray(lps)
-        self.stats["decode_steps"] += 1
-        self.stats["decode_slot_steps"] += len(steps)
-        self._count_triplets(self.max_batch, kw)
+        return steps, toks, dl, cl, width, beam_groups
+
+    def _commit_decode(self, steps, sampled, lps, finished) -> None:
+        """Accept each slot's confirmed prefix of the step's sampled
+        tokens, roll back the rest, and publish the written pages."""
         step_drafted = step_accepted = 0
         for step in steps:
             slot = step.slot
@@ -988,34 +1047,34 @@ class ServingEngine:
             ema = self.stats["accept_rate_ema"]
             self.stats["accept_rate_ema"] = rate if ema == 0.0 \
                 else 0.8 * ema + 0.2 * rate
-        if beam_groups:
-            tkv = np.asarray(tkv)
-            tki = np.asarray(tki)
-            for group in beam_groups.values():
-                if not group.slots:
-                    continue
-                # Each group sees exactly its own top-2*width slice, so
-                # its expansion is independent of what other live beam
-                # groups made the call compute.
-                k = 2 * group.width
-                per_slot = {
-                    s: list(zip(tki[s, 0, :k].tolist(),
-                                tkv[s, 0, :k].tolist()))
-                    for s in group.slots}
-                before_tok = self.sched.tokens_emitted
-                before_forks = self.sched.forks
-                before_stops = self.sched.beam_early_stops
-                fr = self.sched.beam_reorder(group, per_slot)
-                self.stats["generated_tokens"] += \
-                    self.sched.tokens_emitted - before_tok
-                self.stats["forks"] += self.sched.forks - before_forks
-                self.stats["beam_early_stops"] += \
-                    self.sched.beam_early_stops - before_stops
-                self.stats["beam_steps"] += 1
-                if fr is not None:
-                    finished.append(fr)
-                else:
-                    self._reset_beam_slots(group)
+
+    def _reorder_beams(self, beam_groups, tkv, tki, finished) -> None:
+        """Each live beam group's next tokens from its top-2*width
+        (logprob, token) rows of the step."""
+        for group in beam_groups.values():
+            if not group.slots:
+                continue
+            # Each group sees exactly its own top-2*width slice, so its
+            # expansion is independent of what other live beam groups
+            # made the call compute.
+            k = 2 * group.width
+            per_slot = {
+                s: list(zip(tki[s, 0, :k].tolist(), tkv[s, 0, :k].tolist()))
+                for s in group.slots}
+            before_tok = self.sched.tokens_emitted
+            before_forks = self.sched.forks
+            before_stops = self.sched.beam_early_stops
+            fr = self.sched.beam_reorder(group, per_slot)
+            self.stats["generated_tokens"] += \
+                self.sched.tokens_emitted - before_tok
+            self.stats["forks"] += self.sched.forks - before_forks
+            self.stats["beam_early_stops"] += \
+                self.sched.beam_early_stops - before_stops
+            self.stats["beam_steps"] += 1
+            if fr is not None:
+                finished.append(fr)
+            else:
+                self._reset_beam_slots(group)
 
     def _pow2_width(self, need: int) -> int:
         """Page-table width covering ``need`` pages, rounded up to a

@@ -12,6 +12,11 @@ background *drive task* owns the engine; each iteration
   4. publishes each running request's newly generated tokens to its
      stream.
 
+Steps 1-2 run in the phase ``frontend.intake``, step 4 in
+``frontend.publish``, and the two hops of step 3 between the event loop
+and the executor thread are counted as ``frontend.handoff`` (see
+:mod:`repro.serving.tracing`; the totals sit in ``engine.phases``).
+
 Everything that mutates engine state happens inside the drive task,
 *between* steps - client coroutines only enqueue intents (submit /
 cancel) and read from per-stream queues, so the scheduler and paged
@@ -58,7 +63,9 @@ from __future__ import annotations
 import asyncio
 import collections
 import dataclasses
+import time
 
+from repro.serving import tracing
 from repro.serving.engine import ServingEngine
 from repro.serving.scheduler import (FinishedRequest, InvalidRequestError,
                                      Request)
@@ -111,6 +118,9 @@ class AsyncFrontend:
         self._task: asyncio.Task | None = None
         self._closed = False
         self._failed: BaseException | None = None
+        # perf_counter at the start and end of the latest step in the
+        # executor thread (the frontend.handoff counter's inner ends).
+        self._step_span = (0.0, 0.0)
 
     # ------------------------------------------------------------- client
     @property
@@ -224,8 +234,9 @@ class AsyncFrontend:
         loop = asyncio.get_running_loop()
         try:
             while True:
-                self._apply_cancels()
-                self._apply_submissions()
+                with tracing.Phase(self.engine.phases, "frontend.intake"):
+                    self._apply_cancels()
+                    self._apply_submissions()
                 if not self.engine.sched.has_work:
                     self._idle.set()
                     if self._closed:
@@ -237,9 +248,13 @@ class AsyncFrontend:
                     if not (self._pending or self._cancels):
                         await self._wake.wait()
                     continue
-                finished = await loop.run_in_executor(None,
-                                                      self.engine.step)
-                self._publish(finished)
+                sent = time.perf_counter()
+                finished = await loop.run_in_executor(None, self._step)
+                start, end = self._step_span
+                tracing.add(self.engine.phases, "frontend.handoff",
+                            start - sent + time.perf_counter() - end)
+                with tracing.Phase(self.engine.phases, "frontend.publish"):
+                    self._publish(finished)
         except asyncio.CancelledError:
             self._fail(RuntimeError("drive task cancelled"))
             raise
@@ -248,6 +263,14 @@ class AsyncFrontend:
             # submits reject.  Swallowed here so close() can await the
             # task without re-raising what clients already saw.
             self._fail(e)
+
+    def _step(self) -> list[FinishedRequest]:
+        """One engine step, in the executor thread."""
+        start = time.perf_counter()
+        try:
+            return self.engine.step()
+        finally:
+            self._step_span = (start, time.perf_counter())
 
     def _fail(self, exc: BaseException) -> None:
         """Mark the frontend failed and propagate ``exc`` into every

@@ -20,7 +20,11 @@ Endpoints (one request per connection; every response carries
   * ``GET /healthz`` - 200 while serving, 503 once the frontend failed
     or closed.
   * ``GET /stats`` - engine counters, pool occupancy, per-class queue
-    depths and caps, HTTP counters.
+    depths and caps, HTTP counters, and under ``"phases"`` the
+    step and frontend phases of :mod:`repro.serving.tracing`: per phase
+    ``wall_s`` and ``cpu_s`` (seconds since start; the thread's CPU
+    seconds, so wall minus CPU is time it waited for the interpreter
+    lock or a core) and ``count`` (passes).
 
 Flow control and failure mapping:
 
@@ -367,7 +371,9 @@ class HttpServer:
                 "queues": {name: fe.queue_depth(name)
                            for name in LATENCY_CLASSES},
                 "caps": dict(self.queue_caps),
-                "http": dict(self.http_stats)}
+                "http": dict(self.http_stats),
+                "phases": {name: t._asdict()
+                           for name, t in dict(eng.phases).items()}}
 
     async def _generate(self, reader, writer, headers, body) -> None:
         fe = self.frontend
