@@ -5,16 +5,22 @@ of fixed-size pages, ``(num_pages, Hkv, page_size, d)``, addressed by a
 per-sequence page table ``(B, pages_per_seq)`` - vLLM's PagedAttention
 layout mapped onto the paper's multi-KV-block FAU architecture (Fig. 2):
 
-  * Pools are head-major: the kernel's K/V block ``(1, 1, page, d)`` is
-    one (page, d) tile per (page, kv head), which satisfies Mosaic's
-    block rule (the last two block dims divisible by 8 and 128) for the
-    page sizes in :data:`TPU_PAGE_SIZES`.  A bf16 page of 16 is exactly
-    one native (16, 128) tile.
-
-  * Every page is one KV block.  The kernel walks a sequence's page
-    table with scalar prefetch (the page id feeds the BlockSpec index
-    map, so the DMA engine gathers non-contiguous pages directly from
-    HBM) and streams them through the Alg. 2 online update.
+  * Pools are head-major, so one page's ``(Hkv, page, d)`` slab over
+    all KV heads is contiguous, and one (page, d) tile per (page, kv
+    head) satisfies Mosaic's tiling for the page sizes in
+    :data:`TPU_PAGE_SIZES` (a bf16 page of 16 is one native (16, 128)
+    tile).
+  * A grid step is one block of a slot's pages across every KV head:
+    :func:`pages_per_block` pages, the smallest power of two whose K+V
+    slabs reach :data:`BLOCK_BYTES`.  The page ids come from the
+    scalar-prefetched page table; each live page's slab arrives in one
+    DMA into double-buffered VMEM, the next live block's (this slot's,
+    else the next live slot's first) in flight while the current one is
+    computed.  Blocks past ``kv_lens[b]`` start no copy and do no work,
+    so a step costs the live context, not the table width.
+  * Inside a block the pages go through the Alg. 2 online update one at
+    a time, in order, each KV head on its own (page, d) tile: the
+    arithmetic of a walk of one page per grid step, bit for bit.
   * The kernel emits the same *partial triplet* (m, l, o~) as the dense
     ``decode.py`` kernel, so the log-domain ACC merge (Eq. 16) and the
     LogDiv finalize are reused unchanged.
@@ -59,54 +65,165 @@ def _load_tile(codec, ref, s_ref):
     return codec.decode(tile, s).astype(jnp.float32)
 
 
-def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, *rest,
-                         page_size: int, scale: float, use_hfa: bool,
-                         codec=None):
+def _column(row):
+    """(1, n) -> (n, 1), exactly: the diagonal of the row broadcast to
+    (n, n), summed along lanes (every other term is 0)."""
+    n = row.shape[1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    return jnp.sum(jnp.where(eye, jnp.broadcast_to(row, (n, n)), 0.0),
+                   axis=1, keepdims=True)
+
+
+# VMEM a block's K+V slab over all KV heads should reach: the block is
+# the smallest power of two of pages that fills it (capped by the table
+# width), so a slot's pages arrive in a few large DMAs whatever the head
+# count.  Double-buffered, the slabs take twice this.
+BLOCK_BYTES = 512 * 1024
+
+
+def pages_per_block(k_pages, pages_per_seq: int) -> int:
+    """Pages per grid step for head-major pools ``(P, Hkv, page, d)``."""
+    _, hkv, page_size, d = k_pages.shape
+    page_bytes = 2 * hkv * page_size * d * k_pages.dtype.itemsize
+    n = 1
+    while n * page_bytes < BLOCK_BYTES and n < pages_per_seq:
+        n *= 2
+    return min(n, pages_per_seq)
+
+
+def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_hbm, v_hbm, *rest,
+                         page_size: int, pages_per_block: int, scale: float,
+                         use_hfa: bool, codec=None):
     if codec is not None and codec.has_scales:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest
+        (ks_ref, vs_ref, o_ref, m_ref, l_ref, k_buf, v_buf, sem, state,
+         m_scr, l_scr, acc_scr) = rest
     else:
         ks_ref = vs_ref = None
-        o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest
+        (o_ref, m_ref, l_ref, k_buf, v_buf, sem, state, m_scr, l_scr,
+         acc_scr) = rest
+    nslots, pages_per_seq = pt_ref.shape
+    hkv = q_ref.shape[1]
+    ppb = pages_per_block
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    i = pl.program_id(1)
 
-    @pl.when(j == 0)
+    def block_pages(b, i):
+        """Live pages of block i of slot b: 0 past ``kv_lens[b]``."""
+        live = jnp.minimum((sl_ref[b] + page_size - 1) // page_size,
+                           pages_per_seq)
+        return jnp.clip(live - i * ppb, 0, ppb)
+
+    def dma(b, i, into, n, wait):
+        """Start (or wait for) the copies of the n live pages of block i
+        of slot b into VMEM buffer ``into``: one (Hkv, page, d) slab per
+        page, K and V."""
+        def page(p, _):
+            pid = pt_ref[b, i * ppb + p]
+            for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                c = pltpu.make_async_copy(hbm.at[pid], buf.at[into, p],
+                                          sem.at[into])
+                if wait:
+                    c.wait()
+                else:
+                    c.start()
+        jax.lax.fori_loop(0, n, page, None)
+
+    def next_block(b, i):
+        """(slot, block) of the live block after (b, i) in grid order;
+        slot == nslots when there is none."""
+        def next_slot():
+            return jax.lax.fori_loop(
+                b + 1, nslots,
+                lambda c, f: jnp.where((f == nslots) & (sl_ref[c] > 0), c,
+                                       f),
+                nslots)
+        more = block_pages(b, i + 1) > 0
+        return (jax.lax.cond(more, lambda: b, next_slot),
+                jnp.where(more, i + 1, 0))
+
+    @pl.when((b == 0) & (i == 0))
+    def _start_kernel():
+        state[0] = 0        # buffer holding (or about to hold) this block
+        state[1] = 0        # 1 once the first live block's copies started
+
+    @pl.when(i == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32)           # (G, d)
-    k = _load_tile(codec, k_ref, ks_ref)          # (page, d)
-    v = _load_tile(codec, v_ref, vs_ref)          # (page, d)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    kv_ids = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    mask = kv_ids < sl_ref[b]
-    s = jnp.where(mask, s, NEG_INF)
+    n = block_pages(b, i)
 
-    m_prev = m_scr[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    if use_hfa:
-        alpha = bitmath.exp2_hfa_rail(
-            bitmath.quant_rail(jnp.minimum(m_prev - m_new, 0.0)))
-        p = bitmath.exp2_hfa_rail(bitmath.quant_rail(s - m_new[:, None]))
-    else:
-        alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
-        p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(mask & (m_new != NEG_INF)[:, None], p, 0.0)
+    @pl.when(n > 0)
+    def _block():
+        cur = state[0]
 
-    l_scr[:, 0] = l_scr[:, 0] * alpha + jnp.sum(p, axis=1)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_scr[:, 0] = m_new
+        @pl.when(state[1] == 0)
+        def _first():
+            dma(b, i, cur, n, wait=False)
+            state[1] = 1
 
-    @pl.when(j == nj - 1)
+        nxt_b, nxt_i = next_block(b, i)
+
+        @pl.when(nxt_b < nslots)
+        def _prefetch():
+            dma(nxt_b, nxt_i, 1 - cur, block_pages(nxt_b, nxt_i),
+                wait=False)
+
+        dma(b, i, cur, n, wait=True)
+
+        def load(buf, s_ref, p, h):
+            """Page p, head h of the block as f32 (page, d)."""
+            tile = buf[cur, p, h]
+            if codec is None:
+                return tile.astype(jnp.float32)
+            s = None if s_ref is None else _column(s_ref[0, p, pl.ds(h, 1)])
+            return codec.decode(tile, s).astype(jnp.float32)
+
+        def page(p, _):
+            # One page's online update per KV head: the arithmetic of a
+            # (slot, head, page) tile, in page order.  Pages past the
+            # live length are never visited - on them the update is the
+            # identity (alpha = 1, p = 0 on both rails).
+            kv_ids = (i * ppb + p) * page_size
+            for h in range(hkv):
+                q = q_ref[0, h].astype(jnp.float32)       # (G, d)
+                k = load(k_buf, ks_ref, p, h)             # (page, d)
+                v = load(v_buf, vs_ref, p, h)             # (page, d)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                mask = kv_ids + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1) < sl_ref[b]
+                s = jnp.where(mask, s, NEG_INF)
+
+                m_prev = m_scr[h, :, 0]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+                if use_hfa:
+                    alpha = bitmath.exp2_hfa_rail(bitmath.quant_rail(
+                        jnp.minimum(m_prev - m_new, 0.0)))
+                    pr = bitmath.exp2_hfa_rail(
+                        bitmath.quant_rail(s - m_new[:, None]))
+                else:
+                    alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
+                    pr = jnp.exp(s - m_new[:, None])
+                pr = jnp.where(mask & (m_new != NEG_INF)[:, None], pr, 0.0)
+
+                l_scr[h, :, 0] = l_scr[h, :, 0] * alpha + jnp.sum(pr, axis=1)
+                acc_scr[h] = acc_scr[h] * alpha[:, None] + jax.lax.dot_general(
+                    pr, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_scr[h, :, 0] = m_new
+
+        jax.lax.fori_loop(0, n, page, None)
+        state[0] = 1 - cur
+
+    @pl.when(i == pl.num_programs(1) - 1)
     def _finalize():
-        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-        m_ref[0, 0, :, 0] = m_scr[:, 0]
-        l_ref[0, 0, :, 0] = l_scr[:, 0]
+        o_ref[0] = acc_scr[...].astype(o_ref.dtype)
+        m_ref[0, :, :, 0] = m_scr[:, :, 0]
+        l_ref[0, :, :, 0] = l_scr[:, :, 0]
 
 
 def paged_decode_partial_pallas(
@@ -125,14 +242,15 @@ def paged_decode_partial_pallas(
 ):
     """Partial paged decode attention: one block-FAU triplet per (b, hkv).
 
-    Page-table entries past ``ceil(kv_lens[b] / page)`` may be any valid
-    page id (their contribution is masked out); ``kv_lens[b] == 0`` marks
-    a free slot and yields an all-zero triplet.
+    Only a slot's live pages, the first ``ceil(kv_lens[b] / page)`` of
+    its table row, are used; entries past them may hold anything.
+    ``kv_lens[b] == 0`` marks a free slot and yields an all-zero
+    triplet.
 
     ``codec`` (a :class:`repro.kernels.page_codec.PageCodec`, or None for
     the raw fp pool) decodes each page tile inside the loop; codecs with
-    scales stream the matching sidecar page through the same
-    scalar-prefetch index map as the KV pages.
+    scales read the table's sidecar pages, gathered here as (Hkv, page)
+    rows per page.
 
     Returns:
       (o~, m, l): o~ (B, Hkv, G, d) unnormalized f32 accumulator, m/l
@@ -143,40 +261,52 @@ def paged_decode_partial_pallas(
     _, hkv_p, page_size, _ = k_pages.shape
     assert hkv_p == hkv, (hkv_p, hkv)
     pages_per_seq = page_table.shape[1]
+    ppb = pages_per_block(k_pages, pages_per_seq)
+    nblocks = -(-pages_per_seq // ppb)
     scale_v = (1.0 / d ** 0.5) if scale is None else scale
-    has_scales = codec is not None and codec.has_scales
+    page_table = page_table.astype(jnp.int32)
+    kv_lens = kv_lens.astype(jnp.int32)
 
     kernel = functools.partial(_paged_decode_kernel, page_size=page_size,
-                               scale=scale_v, use_hfa=use_hfa, codec=codec)
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b, h, j, pt, sl: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
-    ]
+                               pages_per_block=ppb, scale=scale_v,
+                               use_hfa=use_hfa, codec=codec)
+    per_slot = lambda b, i, pt, sl: (b, 0, 0, 0)  # noqa: E731
+    in_specs = [pl.BlockSpec((1, hkv, g, d), per_slot),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY)]
     operands = [q, k_pages, v_pages]
-    if has_scales:
-        in_specs += [
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, j, pt, sl: (pt[b, j], h, 0, 0)),
-        ]
-        operands += [k_scales, v_scales]
+    if codec is not None and codec.has_scales:
+        # The sidecars' (page, 1) minor dims are no DMA slab on the TPU,
+        # so the table's scales are gathered into (B, pages, Hkv, page)
+        # and streamed a block at a time.  Past the last live block the
+        # index stays put, so dead blocks fetch nothing.
+        pad = ((0, 0), (0, nblocks * ppb - pages_per_seq))
+        rows = jnp.pad(page_table, pad)
+        bk = ppb * page_size
+
+        def last_live(b, i, pt, sl):
+            return (b, jnp.minimum(i, jnp.maximum((sl[b] + bk - 1) // bk - 1,
+                                                  0)), 0, 0)
+        in_specs += [pl.BlockSpec((1, ppb, hkv, page_size), last_live)] * 2
+        operands += [jnp.take(k_scales[..., 0], rows, axis=0),
+                     jnp.take(v_scales[..., 0], rows, axis=0)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, pages_per_seq),
+        grid=(b, nblocks),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda b, h, j, pt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda b, h, j, pt, sl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, g, 1), lambda b, h, j, pt, sl: (b, h, 0, 0)),
+            pl.BlockSpec((1, hkv, g, d), per_slot),
+            pl.BlockSpec((1, hkv, g, 1), per_slot),
+            pl.BlockSpec((1, hkv, g, 1), per_slot),
         ],
         scratch_shapes=[
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, LANES), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
+            pltpu.VMEM((2, ppb) + k_pages.shape[1:], k_pages.dtype),
+            pltpu.VMEM((2, ppb) + v_pages.shape[1:], v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((hkv, g, LANES), jnp.float32),
+            pltpu.VMEM((hkv, g, LANES), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
         ],
     )
     o, m, l = pl.pallas_call(
@@ -187,11 +317,13 @@ def paged_decode_partial_pallas(
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, hkv, g, 1), jnp.float32),
         ],
+        # Both axes in order: each live block prefetches the next one,
+        # which may belong to a later slot.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name="paged_flash_decode_partial",
-    )(page_table.astype(jnp.int32), kv_lens.astype(jnp.int32), *operands)
+    )(page_table, kv_lens, *operands)
     return o, m[..., 0], l[..., 0]
 
 

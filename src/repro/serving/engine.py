@@ -389,6 +389,11 @@ class ServingEngine:
                       "generated_tokens": 0, "preemptions": 0,
                       "cow_copies": 0, "rejected": 0, "decode_steps": 0,
                       "decode_slot_steps": 0, "decode_tokens": 0,
+                      # Pages a layer's paged decode kernel walks (each
+                      # slot's live pages) against the slots x page-table
+                      # width it would walk page by page, summed over
+                      # decode steps without drafts:
+                      "decode_kv_pages": 0, "decode_kv_table_pages": 0,
                       "draft_tokens": 0, "draft_accepted": 0,
                       # Draft-quality EMA (alpha 0.2 over verify steps
                       # that proposed >= 1 draft) and the per-step draft
@@ -927,6 +932,11 @@ class ServingEngine:
         with self._phase("engine.decode.commit"):
             self.stats["decode_steps"] += 1
             self.stats["decode_slot_steps"] += len(steps)
+            if kw == 1:
+                self.stats["decode_kv_pages"] += sum(
+                    self.cache.pages_for(int(dl[s.slot] + cl[s.slot]))
+                    for s in steps)
+                self.stats["decode_kv_table_pages"] += self.max_batch * width
             self._count_triplets(self.max_batch, kw)
             self._commit_decode(steps, sampled, lps, finished)
             if beam_groups:

@@ -344,6 +344,76 @@ def test_ops_paged_verify_jnp_matches_pallas_and_decode():
     np.testing.assert_allclose(one, dec, atol=1e-5)
 
 
+# ------------------------- paged decode blocks vs the page-at-a-time walk
+# (Hkv, G, table width): qwen3-1.7b, qwen1.5-4b (its width no multiple of
+# the block) and a TP-4 shard of qwen3-1.7b; d 128, page 16.  The fp pool
+# is f32: off the TPU, XLA may sum a bf16 tile's (1, 16) x (16, 128) dot
+# in another order inside each kernel (paged_verify against the parent
+# decode kernel too), while both kernels agree bit for bit on the chip.
+# Every rail x codec at qwen3-1.7b's heads; both rails and all three
+# codecs at the other two.
+DECODE_BLOCK_CASES = (
+    [(hfa, name, 8, 2, 64) for hfa in (False, True)
+     for name in ("fp", "int8", "log16")]
+    + [(False, "fp", 20, 1, 21), (True, "int8", 20, 1, 21),
+       (False, "log16", 20, 1, 21)]
+    + [(True, "fp", 2, 2, 128), (False, "int8", 2, 2, 128),
+       (True, "log16", 2, 2, 128)])
+
+
+@pytest.mark.parametrize("use_hfa,name,hkv,g,width", DECODE_BLOCK_CASES)
+def test_paged_decode_blocks_bit_equal_page_walk(use_hfa, name, hkv, g,
+                                                 width):
+    """The decode kernel walks a slot's live pages a block at a time,
+    every KV head per grid step; its triplets must equal paged_verify at
+    K = 1, which walks one (slot, head, page) tile per grid step, bit for
+    bit.  Slots of one page, of exactly one block, of one block + 1
+    token and of the full width, and a free slot; the table shuffled,
+    with pages shared between slots.  The oracle walks each slot's live
+    pages only: a masked page is the identity on both rails."""
+    from repro.kernels import page_codec
+    from repro.kernels import paged_decode as paged
+    from repro.kernels import paged_verify as paged_ver
+    page, d = 16, 128
+    rng = np.random.default_rng(hkv * 100 + width)
+    num_pages = 5 * width + 2
+    c = page_codec.get_codec(name)
+    shape = (num_pages, hkv, page, d)
+    kd, ks = c.encode(_rand(shape, jnp.float32, 601))
+    vd, vs = c.encode(_rand(shape, jnp.float32, 602))
+    ck = dict(codec=None if name == "fp" else c, k_scales=ks, v_scales=vs)
+    ppb = paged.pages_per_block(kd, width)
+    assert ppb < width and (hkv != 20 or width % ppb)
+    lens = np.array([page, ppb * page, ppb * page + 1, width * page, 0],
+                    np.int32)
+    pt = rng.permutation(num_pages)[:5 * width].reshape(5, width)
+    pt[2, :ppb] = pt[1, :ppb]                  # shared with slot 1
+    pt[3, ::7] = pt[0, 0]                      # one page, several times
+    pt = jnp.asarray(pt.astype(np.int32))
+    q = _rand((5, hkv, g, d), jnp.float32, 603)
+    o, m, l = paged.paged_decode_partial_pallas(
+        q, kd, vd, pt, jnp.asarray(lens), use_hfa=use_hfa, interpret=True,
+        **ck)
+    for b, n in enumerate(lens):
+        if n == 0:
+            np.testing.assert_array_equal(np.asarray(o[b]), 0.0)
+            np.testing.assert_array_equal(np.asarray(m[b]),
+                                          np.float32(decode.NEG_INF))
+            np.testing.assert_array_equal(np.asarray(l[b]), 0.0)
+            continue
+        live = -(-int(n) // page)
+        ov, mv, lv = paged_ver.paged_verify_partial_pallas(
+            q[b:b + 1, :, :, None], kd, vd, pt[b:b + 1, :live],
+            jnp.asarray([n - 1], jnp.int32), jnp.ones((1,), jnp.int32),
+            use_hfa=use_hfa, interpret=True, **ck)
+        np.testing.assert_array_equal(np.asarray(o[b]),
+                                      np.asarray(ov[0, :, :, 0]))
+        np.testing.assert_array_equal(np.asarray(m[b]),
+                                      np.asarray(mv[0, ..., 0]))
+        np.testing.assert_array_equal(np.asarray(l[b]),
+                                      np.asarray(lv[0, ..., 0]))
+
+
 # ------------------------------------ COW fork golden parity (groups)
 def _cow_tables(seed, *, b=2, hkv=2, g=2, d=64, page=8, pages_each=3,
                 kw=1):

@@ -346,6 +346,27 @@ def test_engine_matches_dense_generation_under_churn(qwen_smoke):
         assert f.tokens == want, (f.rid, f.preemptions)
 
 
+def test_engine_counts_decode_kv_pages(qwen_smoke):
+    """decode_kv_pages sums each decoding slot's live pages, the pages
+    the decode kernel walks per layer; decode_kv_table_pages the slots x
+    page-table width a page-by-page walk covers.  Page 4, prompts of 5
+    and 9 tokens, 4 and 6 tokens out: the first token comes from the
+    prefill, decode j of a request attends prompt + j tokens."""
+    _, model, params = qwen_smoke
+    engine = ServingEngine(model, params, max_batch=2, page_size=4,
+                           max_seq=40)
+    engine.run([(0, Request(rid=0, prompt=list(range(1, 6)),
+                            max_new_tokens=4)),
+                (0, Request(rid=1, prompt=list(range(1, 10)),
+                            max_new_tokens=6))])
+    # rid 0: ceil(6/4) + ceil(7/4) + ceil(8/4) = 2 + 2 + 2
+    # rid 1: ceil(10/4) + ... + ceil(14/4) = 3 + 3 + 3 + 4 + 4
+    assert engine.stats["decode_kv_pages"] == 6 + 17
+    # five decode steps (rid 1's), each 2 slots x a width of 4 pages
+    assert engine.stats["decode_steps"] == 5
+    assert engine.stats["decode_kv_table_pages"] == 5 * 2 * 4
+
+
 def test_paged_prefill_single_token_prompt(qwen_smoke):
     """A 1-token prompt is a PREFILL (even though S == 1): its KV must
     land in the pages and its logits must match the dense path."""

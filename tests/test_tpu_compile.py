@@ -42,10 +42,10 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _pools(sh, codec, hkv, page=PAGE):
+def _pools(sh, codec, hkv, page=PAGE, num_pages=NUM_PAGES):
     """Shape stand-ins of one layer's K/V pools (and scale sidecars)."""
     c = page_codec.get_codec(codec)
-    shape = (NUM_PAGES, hkv, page, D)
+    shape = (num_pages, hkv, page, D)
     data = jax.ShapeDtypeStruct(shape, c.storage_dtype(jnp.bfloat16),
                                 sharding=sh)
     extra = {}
@@ -64,14 +64,28 @@ def _compile_text(fn, *args):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-@pytest.mark.parametrize("codec,use_hfa,hkv", [
-    ("fp", False, HKV), ("fp", True, HKV), ("int8", True, HKV),
-    ("log16", True, HKV), ("fp", True, HKV // 4)])
-def test_paged_decode_compiles(one_chip, codec, use_hfa, hkv):
-    """Decode on both rails, the int8 / log16 codecs, and the 2 local
-    heads of a TP-4 shard."""
-    pool, extra, c = _pools(one_chip, codec, hkv)
-    q = jax.ShapeDtypeStruct((B, hkv, G, D), jnp.bfloat16, sharding=one_chip)
+# The benchmark's decode shapes: (slots, GQA group, page-table width,
+# pool pages) of qwen3-1.7b (docqa) and qwen1.5-4b (20 KV heads, MHA).
+QWEN3_DOCQA = (16, 2, 256, 3268)
+QWEN15_MIXED = (4, 1, 152, 644)
+
+
+@pytest.mark.parametrize("codec,use_hfa,hkv,shape", [
+    pytest.param("fp", False, HKV, None, id="fp-False-8"),
+    pytest.param("fp", True, HKV, None, id="fp-True-8"),
+    pytest.param("int8", True, HKV, None, id="int8-True-8"),
+    pytest.param("log16", True, HKV, None, id="log16-True-8"),
+    pytest.param("fp", True, HKV // 4, None, id="fp-True-2"),
+    pytest.param("fp", True, HKV, QWEN3_DOCQA, id="qwen3-docqa"),
+    pytest.param("fp", False, 20, QWEN15_MIXED, id="qwen15-mixed-long"),
+])
+def test_paged_decode_compiles(one_chip, codec, use_hfa, hkv, shape):
+    """Decode on both rails, the int8 / log16 codecs, the 2 local heads
+    of a TP-4 shard, and the benchmark's two models at their serving
+    shapes (Mosaic checks each block's VMEM slabs and DMAs)."""
+    b, g, width, num_pages = shape or (B, G, PAGES_PER_SEQ, NUM_PAGES)
+    pool, extra, c = _pools(one_chip, codec, hkv, num_pages=num_pages)
+    q = jax.ShapeDtypeStruct((b, hkv, g, D), jnp.bfloat16, sharding=one_chip)
 
     def f(q, k, v, pt, kl, **sc):
         return paged_decode.paged_decode_partial_pallas(
@@ -79,8 +93,8 @@ def test_paged_decode_compiles(one_chip, codec, use_hfa, hkv):
             **sc)
 
     text = _compile_text(lambda *a: f(*a[:5], **dict(zip(extra, a[5:]))),
-                         q, pool, pool, _i32(one_chip, B, PAGES_PER_SEQ),
-                         _i32(one_chip, B), *extra.values())
+                         q, pool, pool, _i32(one_chip, b, width),
+                         _i32(one_chip, b), *extra.values())
     assert "tpu_custom_call" in text
     assert "paged_flash_decode_partial" in text
 
