@@ -4,8 +4,10 @@ reference.  ``bench/run.py`` is the command; this module is its body.
 
 Set-up (all of it counts in ``setup_s``):
 
-  1. the weights, made on the device from the seed (``weights.make``),
-     checked against the program model's own parameter shapes;
+  1. the weights, made on the device from the seed (``weights.make``)
+     in the layout the configuration's architecture module gives
+     (``bench/archs/``), checked against the program model's own
+     parameter shapes;
   2. the stack the HTTP entry point serves - ``ServingEngine`` ->
      ``AsyncFrontend`` -> ``HttpServer`` on an ephemeral loopback port, as
      the program's ``build_server`` builds it, but with the
@@ -18,9 +20,10 @@ Set-up (all of it counts in ``setup_s``):
      says (see ``loadgen``).
 
 The engine's ``step`` and its scheduler's two planning calls are wrapped
-on the instance: the wrapper logs each step's host times, counters and
-the live context of every attention call, and writes the host spans the
-trace reduction names idle gaps by.  Nothing of the program is changed.
+on the instance: the wrapper logs each step's host times, every numeric
+counter of ``engine.stats``, the program's phase totals, and the live
+context of every attention call, and writes the host spans the trace
+reduction names idle gaps by.  Nothing of the program is changed.
 
 After the window: the device's peak memory is read, the program's state
 freed, and every served request compared with the plain reference
@@ -48,8 +51,6 @@ import e2e  # noqa: E402
 import spec  # noqa: E402
 import traffic  # noqa: E402
 
-COUNTERS = ("steps", "decode_steps", "decode_slot_steps", "prefill_tokens",
-            "cached_prefill_tokens", "generated_tokens", "preemptions")
 WARM_NEW_TOKENS = 2             # first token from prefill, one decode
 
 
@@ -61,36 +62,18 @@ class HarnessError(RuntimeError):
 class Run:
     """What one run measured; the metric readers take it."""
     model: dict                 # the configuration's model keys
+    arch: object                # its architecture module
     peak: dict                  # the chip's peaks
     setup_s: float
     t0: float                   # window, client clock (time.monotonic)
     t1: float
     records: list               # load generator records
     steps: list                 # step log entries inside the window
-    before: dict                # counters before the first of them
+    before: dict                # {"stats", "phases"} before the first
     trace: dict | None = None   # device_trace.reduce() of the window
 
 
 # ------------------------------------------------------------------ model
-def program_config(config: dict):
-    """The program's ModelConfig for a configuration file: the registered
-    architecture with every number set from the file's model keys."""
-    from repro.configs import get_config
-    m, prog, eng = config["model"], config["program"], config["engine"]
-    h = m["num_attention_heads"]
-    return dataclasses.replace(
-        get_config(prog["arch"]), n_layers=m["num_hidden_layers"],
-        d_model=m["hidden_size"], n_heads=h,
-        n_kv_heads=m["num_key_value_heads"],
-        d_head=m.get("head_dim") or m["hidden_size"] // h,
-        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
-        rope_theta=float(m["rope_theta"]), norm_eps=m["rms_norm_eps"],
-        tie_embeddings=m["tie_word_embeddings"], qk_norm=m["qk_norm"],
-        qkv_bias=m["qkv_bias"], attn_impl=prog["attn_impl"],
-        param_dtype=m["torch_dtype"], compute_dtype=m["torch_dtype"],
-        max_seq=eng["max_seq"])
-
-
 def check_layout(model, w) -> None:
     """The benchmark's weight tree must be exactly the program's."""
     import jax
@@ -197,11 +180,22 @@ def warm(engine, plan: dict, eng: dict, seed: int) -> int:
 
 
 # --------------------------------------------------------------- step log
+def counters(engine) -> dict:
+    """The engine's counters as they stand: every numeric entry of
+    ``engine.stats`` under ``"stats"``, a copy of ``engine.phases`` (the
+    phase totals; none where the program keeps none) under
+    ``"phases"``."""
+    return {"stats": {k: v for k, v in engine.stats.items()
+                      if isinstance(v, (int, float))},
+            "phases": dict(getattr(engine, "phases", {}))}
+
+
 class StepLog:
     """Wraps ``engine.step`` and the scheduler's planning calls on the
-    instance: per step, its host start and end, the counters after it,
-    the context length of every decode row and the (start, length,
-    final) of every prefill chunk, and a host span for the trace."""
+    instance: per step, its host start and end, the counters after it
+    (``counters``), the context length of every decode row and the
+    (start, length, final) of every prefill chunk, and a host span for
+    the trace."""
 
     def __init__(self, engine):
         import jax
@@ -232,8 +226,7 @@ class StepLog:
             t0 = time.monotonic()
             with span("bench_step", i=cur["i"]):
                 out = step()
-            rec = dict(cur, t0=t0, t1=time.monotonic(),
-                       stats={k: engine.stats[k] for k in COUNTERS})
+            rec = dict(cur, t0=t0, t1=time.monotonic(), **counters(engine))
             self.steps.append(rec)
             return out
 
@@ -243,14 +236,19 @@ class StepLog:
 
     def window(self, t0: float, t1: float) -> tuple[list, dict]:
         """Steps that ran wholly inside [t0, t1), and the counters before
-        the first of them."""
+        the first of them (``{"stats", "phases"}``, zero before the
+        first step logged)."""
         inside = [s for s in self.steps if s["t0"] >= t0 and s["t1"] <= t1]
         if not inside:
             return [], {}
         i = inside[0]["i"]
-        before = self.steps[i - 1]["stats"] if i > 0 else \
-            {k: 0 for k in COUNTERS}
-        return inside, before
+        if i > 0:
+            prev = self.steps[i - 1]
+            return inside, {"stats": prev["stats"], "phases": prev["phases"]}
+        first = inside[0]
+        return inside, {"stats": {k: 0 for k in first["stats"]},
+                        "phases": {k: (0.0, 0.0, 0)
+                                   for k in first["phases"]}}
 
 
 class CompileLog:
@@ -362,7 +360,7 @@ async def serve(model, w, eng: dict, plan: dict, seed: int, args: list[str],
 
 
 # ------------------------------------------------------------ correctness
-def check(w: dict, m: dict, plan: dict, recs: list, control: bool,
+def check(arch, w: dict, m: dict, plan: dict, recs: list, control: bool,
           share_over: float | None = None) -> dict:
     """Every served token of every request, against the reference: the
     gap of its reference logit below the reference's best at its
@@ -377,8 +375,8 @@ def check(w: dict, m: dict, plan: dict, recs: list, control: bool,
         if not r["tokens"]:
             continue
         req = traffic.request_at(plan, r["stream"], r["idx"])
-        g, c = reference.gaps(w, m, traffic.prompt(plan, req), r["tokens"],
-                              control)
+        g, c = reference.gaps(arch, w, m, traffic.prompt(plan, req),
+                              r["tokens"], control)
         got.append(g)
         ctl.append(c)
     got = np.concatenate(got) if got else np.zeros(0)
@@ -450,7 +448,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     ``control``).  ``log`` takes the progress lines (standard error in
     the command)."""
     c = spec.cell(spec.load(root), workload, root)
-    config, eng = c["config"], c["config"]["engine"]
+    config, eng, arch = c["config"], c["config"]["engine"], c["arch"]
     m = dict(config["model"])
     devs = device_info(c["workload"]["chips"], require_tpu)
     import weights
@@ -465,8 +463,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         log(f"set-up: {what} at {time.monotonic() - t_start:.1f} s, "
             f"{len(compiles.times)} executables built so far")
 
-    model = build_model(program_config(config))
-    w = weights.make(m, model.cfg.padded_vocab, seed, devs[0])
+    model = build_model(arch.program_config(config))
+    w = weights.make(arch, m, model.cfg.padded_vocab, seed, devs[0])
     check_layout(model, w)
     mark("weights made")
 
@@ -479,7 +477,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             model, w, eng, plan, seed, args, trace_dir, mark, engine_hook))
         t0, t1, recs = out["t0"], out["t1"], out["requests"]
         inside, before = steplog.window(t0, t1)
-        run = Run(model=m, peak=peak, setup_s=t0 - t_start,
+        run = Run(model=m, arch=arch, peak=peak, setup_s=t0 - t_start,
                   t0=t0, t1=t1, records=recs, steps=inside, before=before)
         if trace:
             files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -511,7 +509,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     gc.collect()
     t = time.monotonic()
     limits = config["correct"]
-    got = check(w, m, plan, recs, control, limits.get("logit_gap_share_over"))
+    got = check(arch, w, m, plan, recs, control,
+                limits.get("logit_gap_share_over"))
     log(f"reference: {time.monotonic() - t:.1f} s")
     checks = {name: {"value": got[name], "limit": limits[name]}
               for name in ("logit_gap_max", "logit_gap_mean",

@@ -6,9 +6,8 @@ decode step in the window - the change of the engine's
 def read(run):
     if not run.steps:
         return None
-    last = run.steps[-1]["stats"]
-    steps = last["decode_steps"] - run.before["decode_steps"]
+    last, before = run.steps[-1]["stats"], run.before["stats"]
+    steps = last["decode_steps"] - before["decode_steps"]
     if steps <= 0:
         return None
-    return (last["decode_slot_steps"] - run.before["decode_slot_steps"]) \
-        / steps
+    return (last["decode_slot_steps"] - before["decode_slot_steps"]) / steps

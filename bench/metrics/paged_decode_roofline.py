@@ -15,5 +15,5 @@ def read(run):
     ctx = [n for s in run.steps for n in s["dec"]]
     if secs <= 0 or not ctx:
         return None
-    f, b = flops.decode_attention(run.model, ctx)
+    f, b = flops.decode_attention(run.arch, run.model, ctx)
     return flops.roofline_share(f, b, secs, run.peak)[0]

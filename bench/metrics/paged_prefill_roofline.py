@@ -15,5 +15,5 @@ def read(run):
     chunks = [c for s in run.steps for c in s["pre"]]
     if secs <= 0 or not chunks:
         return None
-    f, b = flops.prefill_attention(run.model, chunks)
+    f, b = flops.prefill_attention(run.arch, run.model, chunks)
     return flops.roofline_share(f, b, secs, run.peak)[0]

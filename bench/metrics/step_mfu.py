@@ -8,6 +8,6 @@ import flops
 def read(run):
     if run.trace is None or not run.steps:
         return None
-    f = sum(flops.step_flops(run.model, s["dec"], s["pre"])
+    f = sum(flops.step_flops(run.arch, run.model, s["dec"], s["pre"])
             for s in run.steps)
     return 100.0 * f / (run.trace["window_s"] * run.peak["bf16_flops"])

@@ -6,13 +6,12 @@ the wall seconds, the thread's CPU seconds and the passes so far).
       --trace <0|1>
 
 runs one cell exactly as ``bench/run.py`` does (its result line is the
-second-to-last line of standard output) with two additions the
+second-to-last line of standard output) and reads the copy of
+``engine.phases`` that the harness's step log keeps after each step:
+the window's readings are deltas of those totals over its steps, as
+``decode_batch_mean`` reads its counters.  With one addition the
 benchmark's own reduction does not make yet:
 
-  * the step log also keeps a copy of ``engine.phases`` after each
-    step (``PhaseLog``), so the window's readings are deltas of the
-    counters over its steps, as ``decode_batch_mean`` reads its
-    counters;
   * with ``--trace 1`` the trace reduction also keeps the host spans
     whose names start with ``engine.`` or ``frontend.``, so an idle gap
     of the device inside a step is filed under the phase that held the
@@ -168,30 +167,14 @@ def readings(steps: list, before: dict) -> dict:
 
 # -------------------------------------------------------------------- run
 class PhaseLog(harness.StepLog):
-    """The harness's step log, also keeping ``engine.phases`` after each
-    step (empty where the program has none) and the window it was
-    asked for."""
+    """The harness's step log, keeping the window it was asked for: its
+    steps and the phase totals before the first of them."""
 
-    def __init__(self, engine):
-        super().__init__(engine)
-        logged = engine.step
-        self.window_steps: tuple[list, dict] = ([], {})
-
-        def step():
-            out = logged()
-            self.steps[-1]["phases"] = dict(getattr(engine, "phases", {}))
-            return out
-
-        engine.step = step
+    window_steps: tuple[list, dict] = ([], {})
 
     def window(self, t0: float, t1: float) -> tuple[list, dict]:
         inside, before = super().window(t0, t1)
-        phases = {}
-        if inside:
-            i = inside[0]["i"]
-            phases = self.steps[i - 1]["phases"] if i > 0 else \
-                {k: (0.0, 0.0, 0) for k in inside[0]["phases"]}
-        self.window_steps = (inside, phases)
+        self.window_steps = (inside, before.get("phases", {}))
         return inside, before
 
 

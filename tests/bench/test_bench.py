@@ -1,7 +1,8 @@
 """Unit tests of the benchmark's yardstick, on the CPU and without a chip:
 the trace reduction, the FLOP and byte counts, the traffic generator, the
-end-to-end metric definitions, finding a cell's pieces by name, and the
-result line's schema.
+end-to-end metric definitions, finding a cell's pieces (an architecture
+module among them) by name, the step log's counters, and the result
+line's schema.
 
   JAX_PLATFORMS=cpu python -m pytest -q tests/bench/test_bench.py
 """
@@ -10,6 +11,8 @@ import os
 import shutil
 import subprocess
 import sys
+
+import types
 
 import pytest
 
@@ -26,6 +29,7 @@ import traffic  # noqa: E402
 
 QWEN3 = json.load(open(os.path.join(BENCH, "configs",
                                     "qwen3-1.7b-hfa.json")))["model"]
+DENSE = spec.arch(ROOT, "dense")
 
 # ------------------------------------------------------------ trace reduction
 SYNTHETIC = """
@@ -107,26 +111,28 @@ def test_union_and_gaps():
 # -------------------------------------------------------- flops and bytes
 def test_decode_attention_hand_count():
     # qwen3-1.7b: 16 query heads, 8 KV heads, d_head 128, 28 layers, bf16.
-    f, b = flops.decode_attention(QWEN3, [1000, 3000])
+    f, b = flops.decode_attention(DENSE, QWEN3, [1000, 3000])
     assert f == 4 * 16 * 128 * 4000 * 28
     assert b == 28 * (2 * 4000 * 8 * 128 * 2 + 2 * 2 * 16 * 128 * 2)
 
 
 def test_prefill_attention_hand_count():
     # One chunk of 4 at start 10: queries attend 11, 12, 13, 14 rows.
-    f, b = flops.prefill_attention(QWEN3, [(10, 4, True)])
+    f, b = flops.prefill_attention(DENSE, QWEN3, [(10, 4, True)])
     assert f == 4 * 16 * 128 * (11 + 12 + 13 + 14) * 28
     assert b == 28 * (2 * 14 * 8 * 128 * 2 + 2 * 4 * 16 * 128 * 2)
 
 
 def test_step_flops_hand_count():
     layer = 28 * (2048 * (16 + 16) * 128 + 16 * 128 * 2048 + 3 * 2048 * 6144)
-    assert flops.layer_params(QWEN3) == layer
+    assert DENSE.layer_params(QWEN3) == layer
     head = 2048 * 151936
-    got = flops.step_flops(QWEN3, [100], [(0, 8, False), (8, 8, True)])
+    got = flops.step_flops(DENSE, QWEN3, [100], [(0, 8, False),
+                                                 (8, 8, True)])
     want = (2 * layer * 17 + 2 * head * 2
-            + flops.decode_attention(QWEN3, [100])[0]
-            + flops.prefill_attention(QWEN3, [(0, 8, 0), (8, 8, 0)])[0])
+            + flops.decode_attention(DENSE, QWEN3, [100])[0]
+            + flops.prefill_attention(DENSE, QWEN3,
+                                      [(0, 8, 0), (8, 8, 0)])[0])
     assert got == want
 
 
@@ -215,14 +221,53 @@ def test_percentile_is_linear_between_ranks():
 
 
 # --------------------------------------------------------- finding by name
+TOY_MODEL = {"num_attention_heads": 4, "num_key_value_heads": 2,
+             "head_dim": 8, "hidden_size": 32, "vocab_size": 64,
+             "torch_dtype": "float32"}
+TOY_ARCH = '''"""A toy architecture: a period of four layers, the first three
+attending a window of 8 rows, the fourth its whole context; 4 experts a
+layer behind a router the program keeps in float32."""
+
+
+def shapes(m, padded_vocab):
+    d = m["hidden_size"]
+    return {"embed/emb": (padded_vocab, d),
+            "layers/l0/router": (4, d, 4),
+            "layers/l0/wg": (4, 4, d, 16),
+            "final_norm/scale": (d,)}
+
+
+def leaf(path, shape, m):
+    """The router in float32 whatever the configuration's dtype; an
+    expert matrix (L, E, d, ff) sums its d inputs, not its experts."""
+    if path == "embed/emb":
+        return "embed", None, m["torch_dtype"]
+    if path == "final_norm/scale":
+        return "norm", None, m["torch_dtype"]
+    if path.endswith("/router"):
+        return "matrix", shape[1], "float32"
+    return "matrix", shape[2], m["torch_dtype"]
+
+
+def layer_windows(m):
+    return [8, 8, 8, None]
+
+
+def layer_params(m):
+    return 1000.0
+'''
+
+
 @pytest.fixture
 def scratch_root(tmp_path):
     """A checkout holding this benchmark plus a throwaway configuration,
-    mix and metric, added as files and entries only."""
+    architecture, mix and metric, added as files and entries only."""
     shutil.copytree(BENCH, tmp_path / "bench")
     bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     (tmp_path / "bench" / "configs" / "toy.json").write_text(
-        json.dumps({"model": {"vocab_size": 64}, "engine": {}}))
+        json.dumps({"model": TOY_MODEL, "engine": {},
+                    "program": {"bench_arch": "toy_windowed"}}))
+    (tmp_path / "bench" / "archs" / "toy_windowed.py").write_text(TOY_ARCH)
     (tmp_path / "bench" / "traffic" / "toy-mix.json").write_text(
         json.dumps({"loop": "closed", "streams": 1,
                     "unique": {"dist": "fixed", "value": 4},
@@ -246,11 +291,106 @@ def scratch_root(tmp_path):
 def test_cell_pieces_are_found_by_name(scratch_root):
     c = spec.cell(spec.load(scratch_root), "toy.mix", scratch_root)
     assert c["config"]["model"]["vocab_size"] == 64
+    assert c["arch"].layer_windows(TOY_MODEL) == [8, 8, 8, None]
     assert c["traffic"].endswith("toy-mix.json")
     assert [m["name"] for m in c["per_layer"]] == ["toy_metric"]
     assert spec.reader(scratch_root, "toy_metric")(None) == 42.0
     # The metrics without a workloads list apply to every cell.
     assert {m["name"] for m in c["end_to_end"]} >= {"setup_s"}
+
+
+def test_new_architecture_counts_rows_of_its_windows(scratch_root):
+    arch = spec.cell(spec.load(scratch_root), "toy.mix", scratch_root)["arch"]
+    h, hkv, dh, b = 4, 2, 8, 4
+    # Decode, contexts 5 and 20: a windowed layer attends 5 + 8 rows,
+    # the full one 5 + 20.
+    f, by = flops.decode_attention(arch, TOY_MODEL, [5, 20])
+    assert f == 4 * h * dh * (3 * 13 + 25)
+    assert by == 3 * (2 * 13 * hkv * dh * b + 2 * 2 * h * dh * b) + \
+        (2 * 25 * hkv * dh * b + 2 * 2 * h * dh * b)
+    # Prefill: a chunk of 4 at 6 (queries attend 7, 8, 9, 10 rows;
+    # windowed 7, 8, 8, 8 of the same 10 K/V rows) and one of 4 at 20
+    # (21..24 rows; windowed 8 each, of the 11 rows 13..23).
+    f, by = flops.prefill_attention(arch, TOY_MODEL, [(6, 4, False),
+                                                      (20, 4, True)])
+    assert f == 4 * h * dh * (3 * (31 + 32) + (34 + 90))
+    assert by == 3 * (2 * (10 + 11) * hkv * dh * b + 2 * 8 * h * dh * b) + \
+        (2 * (10 + 24) * hkv * dh * b + 2 * 8 * h * dh * b)
+    assert flops.step_flops(arch, TOY_MODEL, [5], []) == \
+        2 * 1000.0 + 2 * 32 * 64 + flops.decode_attention(
+            arch, TOY_MODEL, [5])[0]
+
+
+def test_new_architecture_owns_its_leaves_dtypes_and_fills(scratch_root):
+    import jax.numpy as jnp
+    import numpy as np
+
+    import harness
+    import weights
+    arch = spec.cell(spec.load(scratch_root), "toy.mix", scratch_root)["arch"]
+    m = dict(TOY_MODEL, torch_dtype="bfloat16")
+    w = weights.make(arch, m, 128, 2 ** 40 + 3)
+    leaves = weights.flat(w)
+    assert {k: v.dtype for k, v in leaves.items()} == {
+        "embed/emb": jnp.bfloat16, "layers/l0/router": jnp.float32,
+        "layers/l0/wg": jnp.bfloat16, "final_norm/scale": jnp.bfloat16}
+    # The fan-in is the module's: d = 32 inputs, not the 4 experts.
+    wg = np.asarray(leaves["layers/l0/wg"], np.float32)
+    assert abs(wg.std() - 32 ** -0.5) < 0.01
+    emb = np.asarray(leaves["embed/emb"], np.float32)
+    assert not emb[64:].any() and np.abs(emb[:64]).min(axis=1).all()
+
+    class Model:
+        """The program's tree, its router in ``router_dtype``."""
+
+        def __init__(self, router_dtype):
+            self.router_dtype = router_dtype
+
+        def init(self, key):
+            bf = jnp.bfloat16
+            return {"embed": {"emb": jnp.zeros((128, 32), bf)},
+                    "layers": {"l0": {
+                        "router": jnp.zeros((4, 32, 4), self.router_dtype),
+                        "wg": jnp.zeros((4, 4, 32, 16), bf)}},
+                    "final_norm": {"scale": jnp.zeros((32,), bf)}}
+
+    harness.check_layout(Model(jnp.float32), w)
+    with pytest.raises(harness.HarnessError, match="router"):
+        harness.check_layout(Model(jnp.bfloat16), w)
+
+
+def test_step_log_carries_every_counter_and_the_phases():
+    import harness
+
+    class Engine:
+        """Counters the harness never names, a text entry, and one
+        phase; a step moves them."""
+        stats = {"steps": 0, "toy_pages": 0, "note": "text"}
+        phases = {"engine.plan": (0.0, 0.0, 0)}
+        sched = types.SimpleNamespace(schedule_prefill=None,
+                                      schedule_decode=None)
+
+        def step(self):
+            self.stats["steps"] += 1
+            self.stats["toy_pages"] += 7
+            w, c, n = self.phases["engine.plan"]
+            self.phases["engine.plan"] = (w + 0.5, c + 0.25, n + 1)
+
+    eng = Engine()
+    log = harness.StepLog(eng)
+    for _ in range(3):
+        eng.step()
+    steps = log.steps
+    assert steps[-1]["stats"] == {"steps": 3, "toy_pages": 21}
+    assert steps[-1]["phases"] == {"engine.plan": (1.5, 0.75, 3)}
+    assert steps[0]["phases"] == {"engine.plan": (0.5, 0.25, 1)}
+    inside, before = log.window(steps[1]["t0"], steps[2]["t1"])
+    assert [s["i"] for s in inside] == [1, 2]
+    assert before == {"stats": {"steps": 1, "toy_pages": 7},
+                      "phases": {"engine.plan": (0.5, 0.25, 1)}}
+    inside, before = log.window(steps[0]["t0"], steps[0]["t1"])
+    assert before == {"stats": {"steps": 0, "toy_pages": 0},
+                      "phases": {"engine.plan": (0.0, 0.0, 0)}}
 
 
 def test_every_metric_and_cell_of_the_benchmark_resolves():
@@ -264,6 +404,7 @@ def test_every_metric_and_cell_of_the_benchmark_resolves():
 
 @pytest.mark.parametrize("kind,name", [("workload", "no.such.cell"),
                                        ("reader", "no_such_metric"),
+                                       ("arch", "no_such_arch"),
                                        ("peak", "TPU v99")])
 def test_unknown_names_are_refused(kind, name):
     with pytest.raises(spec.UnknownName):
@@ -271,6 +412,8 @@ def test_unknown_names_are_refused(kind, name):
             spec.cell(spec.load(ROOT), name, ROOT)
         elif kind == "reader":
             spec.reader(ROOT, name)
+        elif kind == "arch":
+            spec.arch(ROOT, name)
         else:
             spec.peak(ROOT, name)
 
