@@ -12,6 +12,7 @@ from repro.configs import (  # noqa: F401
     granite_moe_1b,
     phi3_5_moe_42b,
     hfa_paper,
+    mellum2_12b_a2_5b,
 )
 
 ASSIGNED = [

@@ -14,6 +14,34 @@ Family = Literal["dense", "moe", "hybrid", "ssm", "encdec", "vlm"]
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling (transformers' ``_compute_yarn_parameters``):
+    dims rotating fewer than ``beta_slow`` times over ``original``
+    positions are interpolated by ``factor``, those rotating more than
+    ``beta_fast`` times kept, a linear ramp between; cos and sin are
+    scaled by ``attention_factor``."""
+    factor: float
+    original: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+@dataclasses.dataclass(frozen=True)
+class AttnKind:
+    """One layer's attention: ``window`` None attends the whole causal
+    context, an int the newest ``window`` rows (the query's own
+    included: i - window < j <= i); ``rope_theta`` None takes the
+    config's; ``yarn`` scales the rotary frequencies."""
+    window: int | None = None
+    rope_theta: float | None = None
+    yarn: Yarn | None = None
+
+
+FULL_ATTN = AttnKind()
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: Family = "dense"
@@ -32,12 +60,20 @@ class ModelConfig:
     attn_block: int = 128           # flash KV/Q block size
     serve_attn: str = "xla"         # xla | shardmap_merge (paper ACC merge)
     rope_theta: float = 10000.0
+    # Per-layer attention kinds, repeated over the depth (layer i takes
+    # attn_pattern[i % len]); empty: every layer FULL_ATTN.
+    attn_pattern: tuple[AttnKind, ...] = ()
     pos_emb: str = "rope"            # rope | learned | sinusoidal
     norm_type: str = "rmsnorm"       # rmsnorm | layernorm
     mlp_type: str = "swiglu"         # swiglu | gelu
 
-    # MoE
+    # MoE.  ``n_experts`` are the experts this model holds: experts
+    # expert_offset .. expert_offset + n_experts - 1 of the router's
+    # ``n_router_experts`` (0: the router has n_experts outputs), the
+    # share of one chip under expert parallelism.
     n_experts: int = 0
+    n_router_experts: int = 0
+    expert_offset: int = 0
     moe_top_k: int = 2
     capacity_factor: float = 1.25
     moe_every: int = 1               # MoE FFN every k-th layer
@@ -84,6 +120,16 @@ class ModelConfig:
             return ["attn" if (i % self.attn_every) == self.attn_offset
                     else "mamba" for i in range(self.n_layers)]
         return ["attn"] * self.n_layers
+
+    def layer_attn(self, i: int) -> AttnKind:
+        """Layer i's attention kind."""
+        if not self.attn_pattern:
+            return FULL_ATTN
+        return self.attn_pattern[i % len(self.attn_pattern)]
+
+    @property
+    def router_experts(self) -> int:
+        return self.n_router_experts or self.n_experts
 
     def ffn_kinds(self) -> list[str]:
         """Per-layer FFN kind: 'dense' or 'moe' (or 'none' for pure SSM)."""
@@ -146,12 +192,14 @@ class ModelConfig:
         return full - moe_all + moe_active
 
     def reduced(self) -> "ModelConfig":
-        """Smoke-test config of the same family (CPU-runnable)."""
+        """Smoke-test config of the same family (CPU-runnable); it keeps
+        one whole period of the attention pattern."""
         return dataclasses.replace(
             self,
             name=self.name + "-smoke",
-            n_layers=min(self.n_layers, 4 if self.family != "hybrid"
-                         else max(self.attn_every, 4)),
+            n_layers=min(self.n_layers, max(
+                4 if self.family != "hybrid" else max(self.attn_every, 4),
+                len(self.attn_pattern))),
             d_model=256,
             n_heads=4,
             n_kv_heads=min(self.n_kv_heads, 2) if self.n_kv_heads
@@ -160,6 +208,8 @@ class ModelConfig:
             d_ff=512 if self.d_ff else 0,
             vocab_size=512,
             n_experts=min(self.n_experts, 4) if self.n_experts else 0,
+            n_router_experts=0,
+            expert_offset=0,
             moe_top_k=min(self.moe_top_k, 2),
             n_enc_layers=min(self.n_enc_layers, 2),
             enc_seq=64 if self.n_enc_layers else 0,

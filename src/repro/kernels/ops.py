@@ -262,7 +262,7 @@ def _decode_jnp_grouped(qg, k_cache, v_cache, kv_len, *, scale, use_hfa,
 
 
 def _prefill_jnp_partial(qg, k_cache, v_cache, q_pos, kv_lens, *, scale,
-                         use_hfa, acc_dtype):
+                         use_hfa, acc_dtype, window=None):
     """Grouped-GQA chunked-prefill *partial* attention (block-FAU form).
 
     Same math as the Pallas kernels' triplet contract: per query row,
@@ -281,7 +281,8 @@ def _prefill_jnp_partial(qg, k_cache, v_cache, q_pos, kv_lens, *, scale,
 
     qg: (B, Hkv, G, L, d); k_cache/v_cache: (B, S, Hkv, d);
     q_pos: (B, L) absolute position per chunk row; kv_lens: (B,) valid
-    KV length.
+    KV length; ``window``: a row at position p attends only ``> p -
+    window`` (every row is still read and masked here).
     Returns (o~ (B, Hkv, G, L, d) f32, m (B, Hkv, G, L), l (B, Hkv, G, L)).
     """
     b, _, _, _, d = qg.shape
@@ -292,6 +293,8 @@ def _prefill_jnp_partial(qg, k_cache, v_cache, q_pos, kv_lens, *, scale,
     kv_ids = jnp.arange(s_len, dtype=jnp.int32)
     mask = (kv_ids[None, None, :] <= q_pos[:, :, None]) & \
         (kv_ids[None, None, :] < kv_lens.astype(jnp.int32)[:, None, None])
+    if window is not None:
+        mask = mask & (kv_ids[None, None, :] > q_pos[:, :, None] - window)
     s = jnp.where(mask[:, None, None, :, :], s, decode_k.NEG_INF)
     live = jnp.any(mask, axis=-1)                              # (B, L)
     m = jnp.max(s, axis=-1)
@@ -372,6 +375,7 @@ def paged_decode_partials(
     codec=None,
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ):
     """Paged decode partial triplet: (o~ (B,Hkv,G,d), m/l (B,Hkv,G))."""
     b = qg.shape[0]
@@ -380,13 +384,14 @@ def paged_decode_partials(
         return paged_k.paged_decode_partial_pallas(
             qg, k_pages, v_pages, page_table, kv_lens, scale=scale,
             use_hfa=use_hfa, interpret=not _on_tpu(), codec=codec,
-            k_scales=k_scales, v_scales=v_scales)
+            k_scales=k_scales, v_scales=v_scales, window=window)
     k_cache, v_cache = _gathered_kv(k_pages, v_pages, page_table, codec,
                                     k_scales, v_scales)
     kvl = jnp.broadcast_to(jnp.asarray(kv_lens, jnp.int32), (b,))
     o, m, l = _prefill_jnp_partial(qg[:, :, :, None, :], k_cache, v_cache,
                                    kvl[:, None] - 1, kvl, scale=scale,
-                                   use_hfa=use_hfa, acc_dtype=qg.dtype)
+                                   use_hfa=use_hfa, acc_dtype=qg.dtype,
+                                   window=window)
     return o[:, :, :, 0, :], m[..., 0], l[..., 0]
 
 
@@ -404,6 +409,7 @@ def paged_prefill_partials(
     codec=None,
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ):
     """Paged chunked-prefill partial triplet: shapes (B,Hkv,G,L,[d])."""
     use_hfa = impl.startswith("hfa")
@@ -411,7 +417,7 @@ def paged_prefill_partials(
         return paged_pf_k.paged_prefill_partial_pallas(
             qg, k_pages, v_pages, page_table, start_pos, kv_lens,
             scale=scale, use_hfa=use_hfa, interpret=not _on_tpu(),
-            codec=codec, k_scales=k_scales, v_scales=v_scales)
+            codec=codec, k_scales=k_scales, v_scales=v_scales, window=window)
     k_cache, v_cache = _gathered_kv(k_pages, v_pages, page_table, codec,
                                     k_scales, v_scales)
     l = qg.shape[3]
@@ -419,7 +425,7 @@ def paged_prefill_partials(
         jnp.arange(l, dtype=jnp.int32)[None]
     return _prefill_jnp_partial(qg, k_cache, v_cache, q_pos, kv_lens,
                                 scale=scale, use_hfa=use_hfa,
-                                acc_dtype=qg.dtype)
+                                acc_dtype=qg.dtype, window=window)
 
 
 def paged_verify_partials(
@@ -436,6 +442,7 @@ def paged_verify_partials(
     codec=None,
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ):
     """Paged speculative-verify partial triplet: shapes (B,Hkv,G,K,[d])."""
     use_hfa = impl.startswith("hfa")
@@ -443,7 +450,7 @@ def paged_verify_partials(
         return paged_v_k.paged_verify_partial_pallas(
             qg, k_pages, v_pages, page_table, seq_lens, chunk_lens,
             scale=scale, use_hfa=use_hfa, interpret=not _on_tpu(),
-            codec=codec, k_scales=k_scales, v_scales=v_scales)
+            codec=codec, k_scales=k_scales, v_scales=v_scales, window=window)
     k_cache, v_cache = _gathered_kv(k_pages, v_pages, page_table, codec,
                                     k_scales, v_scales)
     kw = qg.shape[3]
@@ -452,7 +459,7 @@ def paged_verify_partials(
     kv_lens = sl + chunk_lens.astype(jnp.int32)
     return _prefill_jnp_partial(qg, k_cache, v_cache, q_pos, kv_lens,
                                 scale=scale, use_hfa=use_hfa,
-                                acc_dtype=qg.dtype)
+                                acc_dtype=qg.dtype, window=window)
 
 
 def paged_prefill_attention(
@@ -469,6 +476,7 @@ def paged_prefill_attention(
     codec=None,
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Chunked-prefill attention against a paged KV cache.
 
@@ -491,7 +499,7 @@ def paged_prefill_attention(
     o, m, ell = paged_prefill_partials(
         qg, k_pages, v_pages, page_table, start_pos, kv_lens, impl=impl,
         scale=scale, force_pallas=force_pallas, codec=codec,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, window=window)
     out = decode_k.finalize_decode(o, ell, use_hfa=use_hfa)
     # (B, Hkv, G, L, d) -> (B, L, H, d)
     return jnp.swapaxes(out.reshape(b, h, l, d), 1, 2).astype(q.dtype)
@@ -510,6 +518,7 @@ def paged_decode_attention(
     codec=None,
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Continuous-batching decode attention against a paged KV cache.
 
@@ -529,7 +538,8 @@ def paged_decode_attention(
     o, m, l = paged_decode_partials(qg, k_pages, v_pages, page_table,
                                     kv_lens, impl=impl, scale=scale,
                                     force_pallas=force_pallas, codec=codec,
-                                    k_scales=k_scales, v_scales=v_scales)
+                                    k_scales=k_scales, v_scales=v_scales,
+                                    window=window)
     out = decode_k.finalize_decode(o, l, use_hfa=use_hfa)
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
@@ -548,6 +558,7 @@ def paged_verify_attention(
     codec=None,
     k_scales: jax.Array | None = None,
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Multi-query speculative-verify attention against a paged KV cache.
 
@@ -572,7 +583,7 @@ def paged_verify_attention(
     o, m, l = paged_verify_partials(
         qg, k_pages, v_pages, page_table, seq_lens, chunk_lens, impl=impl,
         scale=scale, force_pallas=force_pallas, codec=codec,
-        k_scales=k_scales, v_scales=v_scales)
+        k_scales=k_scales, v_scales=v_scales, window=window)
     out = decode_k.finalize_decode(o, l, use_hfa=use_hfa)
     # (B, Hkv, G, K, d) -> (B, K, H, d)
     return jnp.swapaxes(out.reshape(b, h, kw, d), 1, 2).astype(q.dtype)

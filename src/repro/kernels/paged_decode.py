@@ -92,9 +92,22 @@ def pages_per_block(k_pages, pages_per_seq: int) -> int:
     return min(n, pages_per_seq)
 
 
+def window_pages(window: int, rows: int, page_size: int) -> int:
+    """Most pages the keys of ``rows`` consecutive queries under a
+    ``window`` can touch: window - 1 + rows keys, starting anywhere in a
+    page."""
+    return -(-(window - 1 + rows) // page_size) + 1
+
+
+def first_window_page(kv_len, window: int, page_size: int):
+    """Page holding the first key row a query at ``kv_len - 1`` attends
+    under ``window``."""
+    return jnp.maximum(kv_len - window, 0) // page_size
+
+
 def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_hbm, v_hbm, *rest,
                          page_size: int, pages_per_block: int, scale: float,
-                         use_hfa: bool, codec=None):
+                         use_hfa: bool, codec=None, window=None):
     if codec is not None and codec.has_scales:
         (ks_ref, vs_ref, o_ref, m_ref, l_ref, k_buf, v_buf, sem, state,
          m_scr, l_scr, acc_scr) = rest
@@ -108,18 +121,26 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_hbm, v_hbm, *rest,
     b = pl.program_id(0)
     i = pl.program_id(1)
 
+    def first(b, i):
+        """Table index of block i's first page of slot b: blocks start
+        at page 0, or with a window at the page holding the window's
+        first row."""
+        if window is None:
+            return i * ppb
+        return first_window_page(sl_ref[b], window, page_size) + i * ppb
+
     def block_pages(b, i):
         """Live pages of block i of slot b: 0 past ``kv_lens[b]``."""
         live = jnp.minimum((sl_ref[b] + page_size - 1) // page_size,
                            pages_per_seq)
-        return jnp.clip(live - i * ppb, 0, ppb)
+        return jnp.clip(live - first(b, i), 0, ppb)
 
     def dma(b, i, into, n, wait):
         """Start (or wait for) the copies of the n live pages of block i
         of slot b into VMEM buffer ``into``: one (Hkv, page, d) slab per
         page, K and V."""
         def page(p, _):
-            pid = pt_ref[b, i * ppb + p]
+            pid = pt_ref[b, first(b, i) + p]
             for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
                 c = pltpu.make_async_copy(hbm.at[pid], buf.at[into, p],
                                           sem.at[into])
@@ -186,7 +207,7 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_hbm, v_hbm, *rest,
             # (slot, head, page) tile, in page order.  Pages past the
             # live length are never visited - on them the update is the
             # identity (alpha = 1, p = 0 on both rails).
-            kv_ids = (i * ppb + p) * page_size
+            kv_ids = (first(b, i) + p) * page_size
             for h in range(hkv):
                 q = q_ref[0, h].astype(jnp.float32)       # (G, d)
                 k = load(k_buf, ks_ref, p, h)             # (page, d)
@@ -194,8 +215,11 @@ def _paged_decode_kernel(pt_ref, sl_ref, q_ref, k_hbm, v_hbm, *rest,
                 s = jax.lax.dot_general(
                     q, k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32) * scale
-                mask = kv_ids + jax.lax.broadcasted_iota(
-                    jnp.int32, s.shape, 1) < sl_ref[b]
+                rows = kv_ids + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                mask = rows < sl_ref[b]
+                if window is not None:
+                    mask = mask & (rows >= sl_ref[b] - window)
                 s = jnp.where(mask, s, NEG_INF)
 
                 m_prev = m_scr[h, :, 0]
@@ -239,6 +263,7 @@ def paged_decode_partial_pallas(
     codec=None,
     k_scales: jax.Array | None = None,  # (P, Hkv, page, 1) f32 sidecar
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ):
     """Partial paged decode attention: one block-FAU triplet per (b, hkv).
 
@@ -246,6 +271,12 @@ def paged_decode_partial_pallas(
     its table row, are used; entries past them may hold anything.
     ``kv_lens[b] == 0`` marks a free slot and yields an all-zero
     triplet.
+
+    With a ``window`` the query attends only rows ``>= kv_lens[b] -
+    window``: each slot's walk starts at the page holding the first of
+    them (rows before it in that page are masked), the grid spans only
+    the blocks a window can fill, and the call is named
+    ``paged_flash_decode_window``.
 
     ``codec`` (a :class:`repro.kernels.page_codec.PageCodec`, or None for
     the raw fp pool) decodes each page tile inside the loop; codecs with
@@ -261,15 +292,17 @@ def paged_decode_partial_pallas(
     _, hkv_p, page_size, _ = k_pages.shape
     assert hkv_p == hkv, (hkv_p, hkv)
     pages_per_seq = page_table.shape[1]
-    ppb = pages_per_block(k_pages, pages_per_seq)
-    nblocks = -(-pages_per_seq // ppb)
+    span = pages_per_seq if window is None else \
+        min(pages_per_seq, window_pages(window, 1, page_size))
+    ppb = pages_per_block(k_pages, span)
+    nblocks = -(-span // ppb)
     scale_v = (1.0 / d ** 0.5) if scale is None else scale
     page_table = page_table.astype(jnp.int32)
     kv_lens = kv_lens.astype(jnp.int32)
 
     kernel = functools.partial(_paged_decode_kernel, page_size=page_size,
                                pages_per_block=ppb, scale=scale_v,
-                               use_hfa=use_hfa, codec=codec)
+                               use_hfa=use_hfa, codec=codec, window=window)
     per_slot = lambda b, i, pt, sl: (b, 0, 0, 0)  # noqa: E731
     in_specs = [pl.BlockSpec((1, hkv, g, d), per_slot),
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -280,13 +313,29 @@ def paged_decode_partial_pallas(
         # so the table's scales are gathered into (B, pages, Hkv, page)
         # and streamed a block at a time.  Past the last live block the
         # index stays put, so dead blocks fetch nothing.
-        pad = ((0, 0), (0, nblocks * ppb - pages_per_seq))
-        rows = jnp.pad(page_table, pad)
-        bk = ppb * page_size
+        if window is None:
+            pad = ((0, 0), (0, nblocks * ppb - pages_per_seq))
+            rows = jnp.pad(page_table, pad)
+            bk = ppb * page_size
 
-        def last_live(b, i, pt, sl):
-            return (b, jnp.minimum(i, jnp.maximum((sl[b] + bk - 1) // bk - 1,
-                                                  0)), 0, 0)
+            def last_live(b, i, pt, sl):
+                return (b, jnp.minimum(
+                    i, jnp.maximum((sl[b] + bk - 1) // bk - 1, 0)), 0, 0)
+        else:
+            # The blocks start at each slot's window: gather its pages
+            # from there, the last live page repeated past the end.
+            first = first_window_page(kv_lens, window, page_size)
+            last = jnp.maximum((kv_lens + page_size - 1) // page_size - 1,
+                               0)
+            idx = jnp.minimum(first[:, None] + jnp.arange(nblocks * ppb),
+                              last[:, None])
+            rows = jnp.take_along_axis(page_table, idx, axis=1)
+
+            def last_live(b, i, pt, sl):
+                n = jnp.maximum(sl[b] - window, 0) // page_size
+                live = (sl[b] + page_size - 1) // page_size - n
+                return (b, jnp.minimum(
+                    i, jnp.maximum((live + ppb - 1) // ppb - 1, 0)), 0, 0)
         in_specs += [pl.BlockSpec((1, ppb, hkv, page_size), last_live)] * 2
         operands += [jnp.take(k_scales[..., 0], rows, axis=0),
                      jnp.take(v_scales[..., 0], rows, axis=0)]
@@ -322,7 +371,8 @@ def paged_decode_partial_pallas(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="paged_flash_decode_partial",
+        name=("paged_flash_decode_partial" if window is None
+              else "paged_flash_decode_window"),
     )(page_table, kv_lens, *operands)
     return o, m[..., 0], l[..., 0]
 

@@ -31,12 +31,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import bitmath
 from repro.kernels.decode import LANES, NEG_INF
-from repro.kernels.paged_decode import _load_tile, _page_coords, _write_rows
+from repro.kernels.paged_decode import (_load_tile, _page_coords,
+                                       _write_rows, window_pages)
+
+
+def window_page(start, kv_len, j, window: int, page_size: int):
+    """Table index of step j of a windowed walk: from the page of the
+    first row a query at ``start`` attends, stopping at the last live
+    page (past it the index stays put, so nothing more is fetched)."""
+    first = jnp.maximum(start - window + 1, 0) // page_size
+    return jnp.minimum(first + j, jnp.maximum((kv_len - 1) // page_size, 0))
 
 
 def _paged_prefill_kernel(pt_ref, sp_ref, kl_ref, q_ref, k_ref, v_ref,
                           *rest, page_size: int, chunk: int, scale: float,
-                          use_hfa: bool, codec=None):
+                          use_hfa: bool, codec=None, window=None):
     if codec is not None and codec.has_scales:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -52,17 +61,49 @@ def _paged_prefill_kernel(pt_ref, sp_ref, kl_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    if window is None:
+        _prefill_page(b, j, sp_ref, kl_ref, q_ref, k_ref, v_ref, ks_ref,
+                      vs_ref, m_scr, l_scr, acc_scr, page_size=page_size,
+                      chunk=chunk, scale=scale, use_hfa=use_hfa,
+                      codec=codec, window=None, page_idx=j)
+    else:
+        # The walk starts at the page of the chunk's first windowed row;
+        # steps past the chunk's last page do nothing.
+        first = jnp.maximum(sp_ref[b] - window + 1, 0) // page_size
+
+        @pl.when(first + j <= (kl_ref[b] - 1) // page_size)
+        def _page():
+            _prefill_page(b, j, sp_ref, kl_ref, q_ref, k_ref, v_ref,
+                          ks_ref, vs_ref, m_scr, l_scr, acc_scr,
+                          page_size=page_size, chunk=chunk, scale=scale,
+                          use_hfa=use_hfa, codec=codec, window=window,
+                          page_idx=first + j)
+
+    @pl.when(j == nj - 1)
+    def _finalize():
+        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
+        m_ref[0, 0, :, 0] = m_scr[:, 0]
+        l_ref[0, 0, :, 0] = l_scr[:, 0]
+
+
+def _prefill_page(b, j, sp_ref, kl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                  m_scr, l_scr, acc_scr, *, page_size, chunk, scale, use_hfa,
+                  codec, window, page_idx):
+    """One page's online update of the chunk's query rows."""
     q = q_ref[0, 0].astype(jnp.float32)           # (G * chunk, d)
     k = _load_tile(codec, k_ref, ks_ref)          # (page, d)
     v = _load_tile(codec, v_ref, vs_ref)          # (page, d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    kv_ids = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    kv_ids = page_idx * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1)
     # Row r of the flattened (G, chunk) query block is local chunk
     # position r % chunk, i.e. absolute position start + r % chunk.
     q_pos = sp_ref[b] + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 0) % chunk
     mask = (kv_ids <= q_pos) & (kv_ids < kl_ref[b])
+    if window is not None:
+        mask = mask & (kv_ids > q_pos - window)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[:, 0]
@@ -81,11 +122,11 @@ def _paged_prefill_kernel(pt_ref, sp_ref, kl_ref, q_ref, k_ref, v_ref,
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[:, 0] = m_new
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-        m_ref[0, 0, :, 0] = m_scr[:, 0]
-        l_ref[0, 0, :, 0] = l_scr[:, 0]
+
+# Most query rows (GQA group x chunk positions) one call keeps in VMEM:
+# a larger chunk runs as several calls over consecutive query blocks,
+# each a chunk of its own (every row's attention is the same either way).
+MAX_ROWS = 2048
 
 
 def paged_prefill_partial_pallas(
@@ -102,6 +143,7 @@ def paged_prefill_partial_pallas(
     codec=None,
     k_scales: jax.Array | None = None,  # (P, Hkv, page, 1) f32 sidecar
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ):
     """Partial paged chunked-prefill attention.
 
@@ -112,6 +154,13 @@ def paged_prefill_partial_pallas(
     ignores.  Page-table entries past ``ceil(kv_lens[b] / page)`` may be
     any valid page id (masked out).
 
+    With a ``window`` a row at position p attends only ``> p - window``:
+    each sequence's walk starts at the page of its chunk's first such
+    row and spans only the pages the window and the chunk can touch.
+
+    A chunk of more than ``MAX_ROWS`` query rows runs as consecutive
+    query blocks of at most that many.
+
     Returns:
       (o~, m, l): o~ (B, Hkv, G, L, d) unnormalized f32 accumulator,
       m/l (B, Hkv, G, L) running max / sum-of-exps - the same block-FAU
@@ -120,6 +169,15 @@ def paged_prefill_partial_pallas(
       :mod:`repro.kernels.decode`.
     """
     b, hkv, g, chunk, d = q.shape
+    part = max(MAX_ROWS // g, 1)
+    if chunk > part:
+        outs = [paged_prefill_partial_pallas(
+            q[:, :, :, i:i + part], k_pages, v_pages, page_table,
+            start_pos + i, kv_lens, interpret=interpret, scale=scale,
+            use_hfa=use_hfa, codec=codec, k_scales=k_scales,
+            v_scales=v_scales, window=window)
+            for i in range(0, chunk, part)]
+        return tuple(jnp.concatenate(x, axis=3) for x in zip(*outs))
     _, hkv_p, page_size, _ = k_pages.shape
     assert hkv_p == hkv, (hkv_p, hkv)
     pages_per_seq = page_table.shape[1]
@@ -130,27 +188,32 @@ def paged_prefill_partial_pallas(
 
     kernel = functools.partial(_paged_prefill_kernel, page_size=page_size,
                                chunk=chunk, scale=scale_v, use_hfa=use_hfa,
-                               codec=codec)
+                               codec=codec, window=window)
+    if window is None:
+        steps = pages_per_seq
+
+        def kv_map(b, h, j, pt, sp, kl):
+            return (pt[b, j], h, 0, 0)
+    else:
+        steps = min(pages_per_seq, window_pages(window, chunk, page_size))
+
+        def kv_map(b, h, j, pt, sp, kl):
+            return (pt[b, window_page(sp[b], kl[b], j, window, page_size)],
+                    h, 0, 0)
     in_specs = [
         pl.BlockSpec((1, 1, rows, d),
                      lambda b, h, j, pt, sp, kl: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
+        pl.BlockSpec((1, 1, page_size, d), kv_map),
+        pl.BlockSpec((1, 1, page_size, d), kv_map),
     ]
     operands = [q3, k_pages, v_pages]
     if has_scales:
-        in_specs += [
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, j, pt, sp, kl: (pt[b, j], h, 0, 0)),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, page_size, 1), kv_map),
+                     pl.BlockSpec((1, 1, page_size, 1), kv_map)]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, pages_per_seq),
+        grid=(b, hkv, steps),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, rows, d),

@@ -40,12 +40,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import bitmath
 from repro.kernels.decode import LANES, NEG_INF
-from repro.kernels.paged_decode import _load_tile, gather_pages
+from repro.kernels.paged_decode import (_load_tile, gather_pages,
+                                       window_pages)
+from repro.kernels.paged_prefill import window_page
 
 
 def _paged_verify_kernel(pt_ref, sl_ref, cl_ref, q_ref, k_ref, v_ref,
                          *rest, page_size: int, spec_width: int,
-                         scale: float, use_hfa: bool, codec=None):
+                         scale: float, use_hfa: bool, codec=None,
+                         window=None):
     if codec is not None and codec.has_scales:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -61,17 +64,46 @@ def _paged_verify_kernel(pt_ref, sl_ref, cl_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+    args = (b, sl_ref, cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref, m_scr,
+            l_scr, acc_scr)
+    kw = dict(page_size=page_size, spec_width=spec_width, scale=scale,
+              use_hfa=use_hfa, codec=codec, window=window)
+    if window is None:
+        _verify_page(*args, page_idx=j, **kw)
+    else:
+        # The walk starts at the page of the first row the first verify
+        # position attends; steps past the last live page do nothing.
+        first = jnp.maximum(sl_ref[b] - window + 1, 0) // page_size
+
+        @pl.when(first + j <= (sl_ref[b] + cl_ref[b] - 1) // page_size)
+        def _page():
+            _verify_page(*args, page_idx=first + j, **kw)
+
+    @pl.when(j == nj - 1)
+    def _finalize():
+        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
+        m_ref[0, 0, :, 0] = m_scr[:, 0]
+        l_ref[0, 0, :, 0] = l_scr[:, 0]
+
+
+def _verify_page(b, sl_ref, cl_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
+                 m_scr, l_scr, acc_scr, *, page_size, spec_width, scale,
+                 use_hfa, codec, window, page_idx):
+    """One page's online update of the K verify rows."""
     q = q_ref[0, 0].astype(jnp.float32)           # (G * K, d)
     k = _load_tile(codec, k_ref, ks_ref)          # (page, d)
     v = _load_tile(codec, v_ref, vs_ref)          # (page, d)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    kv_ids = j * page_size + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    kv_ids = page_idx * page_size + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 1)
     # Row r of the flattened (G, K) query block is verify position
     # r % K, i.e. absolute position seq_lens[b] + r % K.
     q_pos = sl_ref[b] + jax.lax.broadcasted_iota(
         jnp.int32, s.shape, 0) % spec_width
     mask = (kv_ids <= q_pos) & (kv_ids < sl_ref[b] + cl_ref[b])
+    if window is not None:
+        mask = mask & (kv_ids > q_pos - window)
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[:, 0]
@@ -90,12 +122,6 @@ def _paged_verify_kernel(pt_ref, sl_ref, cl_ref, q_ref, k_ref, v_ref,
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_scr[:, 0] = m_new
 
-    @pl.when(j == nj - 1)
-    def _finalize():
-        o_ref[0, 0] = acc_scr[...].astype(o_ref.dtype)
-        m_ref[0, 0, :, 0] = m_scr[:, 0]
-        l_ref[0, 0, :, 0] = l_scr[:, 0]
-
 
 def paged_verify_partial_pallas(
     q: jax.Array,           # (B, Hkv, G, K, d) grouped verify queries
@@ -111,9 +137,13 @@ def paged_verify_partial_pallas(
     codec=None,
     k_scales: jax.Array | None = None,  # (P, Hkv, page, 1) f32 sidecar
     v_scales: jax.Array | None = None,
+    window: int | None = None,
 ):
     """Partial paged verify attention: one block-FAU triplet per
     (sequence, kv head, verify position).
+
+    With a ``window`` a row at position p attends only ``> p - window``,
+    and each sequence's walk starts at the page of the first such row.
 
     Returns:
       (o~, m, l): o~ (B, Hkv, G, K, d) unnormalized f32 accumulator,
@@ -133,27 +163,33 @@ def paged_verify_partial_pallas(
 
     kernel = functools.partial(_paged_verify_kernel, page_size=page_size,
                                spec_width=spec_width, scale=scale_v,
-                               use_hfa=use_hfa, codec=codec)
+                               use_hfa=use_hfa, codec=codec, window=window)
+    if window is None:
+        steps = pages_per_seq
+
+        def kv_map(b, h, j, pt, sl, cl):
+            return (pt[b, j], h, 0, 0)
+    else:
+        steps = min(pages_per_seq,
+                    window_pages(window, spec_width, page_size))
+
+        def kv_map(b, h, j, pt, sl, cl):
+            return (pt[b, window_page(sl[b], sl[b] + cl[b], j, window,
+                                      page_size)], h, 0, 0)
     in_specs = [
         pl.BlockSpec((1, 1, rows, d),
                      lambda b, h, j, pt, sl, cl: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
+        pl.BlockSpec((1, 1, page_size, d), kv_map),
+        pl.BlockSpec((1, 1, page_size, d), kv_map),
     ]
     operands = [q3, k_pages, v_pages]
     if has_scales:
-        in_specs += [
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, j, pt, sl, cl: (pt[b, j], h, 0, 0)),
-        ]
+        in_specs += [pl.BlockSpec((1, 1, page_size, 1), kv_map),
+                     pl.BlockSpec((1, 1, page_size, 1), kv_map)]
         operands += [k_scales, v_scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, pages_per_seq),
+        grid=(b, hkv, steps),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, rows, d),
@@ -190,7 +226,8 @@ def paged_verify_partial_pallas(
 
 def paged_verify_partial_ref(q, k_pages, v_pages, page_table, seq_lens,
                              chunk_lens, *, scale=None, use_hfa=False,
-                             codec=None, k_scales=None, v_scales=None):
+                             codec=None, k_scales=None, v_scales=None,
+                             window=None):
     """jnp triplet oracle: dense gather + one-shot softmax pieces.
 
     Same signature/returns as :func:`paged_verify_partial_pallas`.  The
@@ -218,6 +255,8 @@ def paged_verify_partial_ref(q, k_pages, v_pages, page_table, seq_lens,
     mask = (kv_ids[None, None, :] <= q_pos) & \
         (kv_ids[None, None, :] < sl + chunk_lens.astype(jnp.int32)[:, None,
                                                                    None])
+    if window is not None:
+        mask = mask & (kv_ids[None, None, :] > q_pos - window)
     s = jnp.where(mask[:, None, None, :, :], s, NEG_INF)
     m = jnp.max(s, axis=-1)
     if use_hfa:

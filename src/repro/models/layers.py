@@ -15,6 +15,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import ops as kops
 
@@ -70,16 +71,49 @@ def sinusoidal_pos(seq: int, d: int, offset=0) -> jax.Array:
 
 
 # ---------------------------------------------------------------- RoPE
-def rope_apply(x: jax.Array, positions: jax.Array, theta: float = 10000.0):
-    """Rotary embedding. x: (B, S, H, dh), positions: (B, S) or (S,)."""
+def yarn_inv_freq(dh: int, theta: float, yarn) -> tuple[np.ndarray, float]:
+    """(inverse frequencies (dh/2,) f32, cos/sin scale) of YaRN, as
+    transformers' ``_compute_yarn_parameters`` computes them: correction
+    dims floored/ceiled, a linear ramp from ``inv_freq / factor`` (dims
+    below the low one) to ``inv_freq`` (above the high one)."""
+    def corr_dim(rot):
+        return (dh * math.log(yarn.original / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dh - 1)
+    if low == high:
+        high += 0.001
+    pos_freqs = np.float32(theta) ** (np.arange(0, dh, 2, dtype=np.float32)
+                                      / np.float32(dh))
+    extra = np.float32(1.0) / pos_freqs
+    inter = np.float32(1.0) / (np.float32(yarn.factor) * pos_freqs)
+    ramp = np.clip((np.arange(dh // 2, dtype=np.float32) - low)
+                   / np.float32(high - low), 0, 1).astype(np.float32)
+    keep = np.float32(1.0) - ramp
+    inv = inter * (np.float32(1.0) - keep) + extra * keep
+    return inv.astype(np.float32), float(yarn.attention_factor)
+
+
+def rope_apply(x: jax.Array, positions: jax.Array, theta: float = 10000.0,
+               yarn=None):
+    """Rotary embedding. x: (B, S, H, dh), positions: (B, S) or (S,).
+    ``yarn`` (a :class:`repro.configs.base.Yarn`) rescales the
+    frequencies and scales cos and sin by its attention factor."""
     dh = x.shape[-1]
     half = dh // 2
-    freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is None:
+        freqs = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                 / half))
+    else:
+        inv, mscale = yarn_inv_freq(dh, theta, yarn)
+        freqs = jnp.asarray(inv)
     if positions.ndim == 1:
         positions = positions[None, :]
     ang = positions[..., None].astype(jnp.float32) * freqs  # (B, S, half)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
@@ -137,6 +171,7 @@ def attention_apply(
     causal: bool = True,
     attn_impl: str | None = None,
     page_state: dict[str, jax.Array] | None = None,
+    attn=None,
 ):
     """Returns (out (B,S,d_model), new_cache).
 
@@ -144,7 +179,19 @@ def attention_apply(
     {"k_pages", "v_pages"}; the paged form additionally needs
     ``page_state`` = {"page_table" (B, J), "seq_lens" (B,)} from the
     serving engine (seq_lens[b] == 0 marks a free slot).
+
+    ``attn`` (a :class:`repro.configs.base.AttnKind`, None: whole
+    context, the config's RoPE) sets this layer's window and rotary
+    embedding; a window runs on the paged path only.
     """
+    window = None if attn is None else attn.window
+    theta = cfg.rope_theta if attn is None or attn.rope_theta is None \
+        else attn.rope_theta
+    yarn = None if attn is None else attn.yarn
+    paged = cache is not None and "k_pages" in cache and kv_input is None
+    if window is not None and not paged:
+        raise NotImplementedError(
+            "windowed attention runs on the paged serving path only")
     b, s, _ = x.shape
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     impl = attn_impl or cfg.attn_impl
@@ -166,12 +213,13 @@ def attention_apply(
             positions = base + jnp.arange(s)
             if positions.ndim == 1:
                 positions = jnp.broadcast_to(positions[None], (b, s))
-        q = rope_apply(q, positions, cfg.rope_theta)
-        k = rope_apply(k, positions, cfg.rope_theta)
+        q = rope_apply(q, positions, theta, yarn)
+        k = rope_apply(k, positions, theta, yarn)
 
-    if cache is not None and "k_pages" in cache and kv_input is None:
+    if paged:
         return _paged_attention(p, q, k, v, cfg, cache, page_state,
-                                impl=impl, causal=causal, x_dtype=x.dtype)
+                                impl=impl, causal=causal, x_dtype=x.dtype,
+                                window=window)
 
     new_cache = cache
     if cache is not None and kv_input is None:
@@ -223,7 +271,7 @@ def attention_apply(
 
 
 def _paged_attention(p, q, k, v, cfg, cache, page_state, *, impl, causal,
-                     x_dtype):
+                     x_dtype, window=None):
     """Attention against a paged block-pool KV cache (serving path).
 
     Decode: append the new token's K/V at seq_lens[b] through the page
@@ -245,6 +293,9 @@ def _paged_attention(p, q, k, v, cfg, cache, page_state, *, impl, causal,
     (:func:`repro.parallel.collectives.shardmap_paged_attention`) -
     each shard scatters/attends its local heads and only the tiny
     (m, l, o~) triplets cross the interconnect.
+
+    ``window`` (static) limits every query to the newest ``window`` rows
+    of its context; the kernels then walk only the pages in it.
     """
     from repro.kernels import page_codec
     from repro.kernels import paged_decode as paged_k
@@ -278,7 +329,8 @@ def _paged_attention(p, q, k, v, cfg, cache, page_state, *, impl, causal,
             lb = jnp.full((b_,), l_, jnp.int32)
         out, new_pools = collectives.shardmap_paged_attention(
             q, k, v, cache, pt, la, lb,
-            mesh=mesh, mode=mode, impl=_decode_impl(impl), codec=codec)
+            mesh=mesh, mode=mode, impl=_decode_impl(impl), codec=codec,
+            window=window)
     elif page_state.get("verify", False):
         # Speculative multi-token verify: scatter the K step tokens at
         # positions seq_lens[b].. (rows past chunk_lens are dropped, so
@@ -292,7 +344,7 @@ def _paged_attention(p, q, k, v, cfg, cache, page_state, *, impl, causal,
             q, new_pools["k_pages"], new_pools["v_pages"], pt, sl, cl,
             impl=_decode_impl(impl), codec=rcodec,
             k_scales=new_pools.get("k_scale"),
-            v_scales=new_pools.get("v_scale"))
+            v_scales=new_pools.get("v_scale"), window=window)
     elif not page_state.get("prefill", False):
         sl = page_state["seq_lens"]
         new_pools = page_codec.encode_write(
@@ -302,17 +354,20 @@ def _paged_attention(p, q, k, v, cfg, cache, page_state, *, impl, causal,
             q, new_pools["k_pages"], new_pools["v_pages"], pt, kv_lens,
             impl=_decode_impl(impl), codec=rcodec,
             k_scales=new_pools.get("k_scale"),
-            v_scales=new_pools.get("v_scale"))
-    elif "start_pos" in page_state:
-        sp = page_state["start_pos"]
-        cl = page_state["chunk_lens"]
+            v_scales=new_pools.get("v_scale"), window=window)
+    elif "start_pos" in page_state or window is not None:
+        # A whole-prompt fresh prefill with a window is the chunk at
+        # position 0 of every row's full length.
+        b_, l_ = q.shape[0], q.shape[1]
+        sp = page_state.get("start_pos", jnp.zeros((b_,), jnp.int32))
+        cl = page_state.get("chunk_lens", jnp.full((b_,), l_, jnp.int32))
         new_pools = page_codec.encode_write(
             paged_pf_k.write_chunk_kv, codec, cache, k, v, pt, sp, cl)
         out = kops.paged_prefill_attention(
             q, new_pools["k_pages"], new_pools["v_pages"], pt, sp, cl,
             impl=_decode_impl(impl), codec=rcodec,
             k_scales=new_pools.get("k_scale"),
-            v_scales=new_pools.get("v_scale"))
+            v_scales=new_pools.get("v_scale"), window=window)
     else:
         # Legacy fresh prefill: pages are storage only - attention runs
         # on the raw chunk, so the codec only affects later reads.

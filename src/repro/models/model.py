@@ -263,7 +263,8 @@ class LM:
 
     def paged_prefill(self, params, layers, tokens, page_table,
                       last_pos=None, start_pos=None, mesh=None,
-                      codec: str = "fp", return_all_logits: bool = False):
+                      codec: str = "fp", return_all_logits: bool = False,
+                      stats: bool = False):
         """Prefill sequences into paged KV storage.
 
         tokens: (B, L) token rows padded to a common length L.
@@ -288,7 +289,8 @@ class LM:
         return_all_logits: keep logits at every position even when
         ``last_pos`` is given - the prompt-logprobs path pays the full
         (B, L, V) projection to score each prompt token.
-        Returns (logits, new layer caches).
+        Returns (logits, new layer caches), and with ``stats`` the expert
+        layers' counters (:meth:`_moe_counts`).
         """
         cfg = self.cfg
         cdt = _dtype(cfg.compute_dtype)
@@ -314,18 +316,23 @@ class LM:
             ps = {"page_table": page_table, "prefill": True, "mesh": mesh,
                   "codec": codec, "start_pos": start_pos,
                   "chunk_lens": last_pos.astype(jnp.int32) + 1}
-        x, new_layers, _ = T.stack_apply(
+        if last_pos is not None:
+            ps["token_valid"] = jnp.arange(tokens.shape[1])[None] <= \
+                last_pos.astype(jnp.int32)[:, None]
+        x, new_layers, aux = T.stack_apply(
             params["layers"], x, cfg, positions=positions, caches=layers,
             cache_pos=0, page_state=ps, causal=True)
         if last_pos is not None and not return_all_logits:
             x = jnp.take_along_axis(x, last_pos[:, None, None].astype(
                 jnp.int32), axis=1)
         x = T._norm_apply(cfg, params["final_norm"], x)
+        if stats:
+            return self._head(params, x), new_layers, _moe_counts(aux)
         return self._head(params, x), new_layers
 
     def paged_verify_step(self, params, layers, tokens, page_table,
                           seq_lens, chunk_lens, mesh=None,
-                          codec: str = "fp"):
+                          codec: str = "fp", stats: bool = False):
         """K-token speculative verify step across every slot.
 
         tokens: (B, K) input tokens per slot - the carry token followed
@@ -347,15 +354,20 @@ class LM:
             tokens.shape[1], dtype=jnp.int32)[None]
         ps = {"page_table": page_table, "seq_lens": seq_lens, "mesh": mesh,
               "codec": codec,
-              "chunk_lens": chunk_lens.astype(jnp.int32), "verify": True}
-        x, new_layers, _ = T.stack_apply(
+              "chunk_lens": chunk_lens.astype(jnp.int32), "verify": True,
+              "token_valid": jnp.arange(tokens.shape[1])[None]
+              < chunk_lens.astype(jnp.int32)[:, None]}
+        x, new_layers, aux = T.stack_apply(
             params["layers"], x, cfg, positions=positions, caches=layers,
             page_state=ps, causal=True)
         x = T._norm_apply(cfg, params["final_norm"], x)
+        if stats:
+            return self._head(params, x), new_layers, _moe_counts(aux)
         return self._head(params, x), new_layers
 
     def paged_decode_step(self, params, layers, tokens, page_table,
-                          seq_lens, mesh=None, codec: str = "fp"):
+                          seq_lens, mesh=None, codec: str = "fp",
+                          stats: bool = False):
         """One continuous-batching decode step across every slot.
 
         tokens: (B, 1) next input token per slot; seq_lens: (B,) int32
@@ -369,12 +381,22 @@ class LM:
         x = self._embed_in(params, tokens, cdt, pos0=0)
         x = constrain(x, ("batch", None, "embed"))
         ps = {"page_table": page_table, "seq_lens": seq_lens, "mesh": mesh,
-              "codec": codec}
-        x, new_layers, _ = T.stack_apply(
+              "codec": codec, "token_valid": (seq_lens > 0)[:, None]}
+        x, new_layers, aux = T.stack_apply(
             params["layers"], x, cfg, positions=seq_lens[:, None],
             caches=layers, page_state=ps, causal=True)
         x = T._norm_apply(cfg, params["final_norm"], x)
+        if stats:
+            return self._head(params, x), new_layers, _moe_counts(aux)
         return self._head(params, x), new_layers
+
+
+def _moe_counts(aux) -> jax.Array:
+    """(2,) int32: the expert layers' (token, choice) rows computed by
+    held experts and (layer, held expert) pairs with a row, summed over
+    layers; zeros for a model without experts."""
+    return jnp.stack([jnp.asarray(aux.get(k, 0), jnp.int32) for k in
+                      ("moe_rows_local", "moe_experts_active")])
 
 
 def build_model(cfg: ModelConfig) -> LM:

@@ -46,7 +46,10 @@ def _ffn_init(key, cfg, kind, dtype):
     return {}, {}
 
 
-def _ffn_apply(p, x, cfg, kind):
+def _ffn_apply(p, x, cfg, kind, page_state=None, expert_group=None):
+    if kind == "moe" and page_state is not None:
+        return moe.moe_serve(p, x, cfg, page_state.get("token_valid"),
+                             group=expert_group)
     if kind == "moe":
         return moe.moe_apply(p, x, cfg)
     if kind == "dense":
@@ -77,8 +80,12 @@ def block_init(key, cfg, kind: str, ffn_kind: str, dtype, cross: bool = False):
 
 def block_apply(p, x, cfg, *, kind: str, ffn_kind: str,
                 positions=None, cache=None, cache_pos=None,
-                enc_cache=None, causal: bool = True, page_state=None):
-    """Returns (x, new_cache, aux_losses)."""
+                enc_cache=None, causal: bool = True, page_state=None,
+                attn=None, expert_group=None):
+    """Returns (x, new_cache, aux_losses).  ``attn`` is the layer's
+    :class:`repro.configs.base.AttnKind` (None: the config's);
+    ``expert_group``: the expert weights are the whole stack, this
+    layer at that index (``moe.moe_serve``)."""
     aux: dict[str, jax.Array] = {}
     h = _norm_apply(cfg, p["norm1"], x)
     if kind == "attn":
@@ -95,7 +102,8 @@ def block_apply(p, x, cfg, *, kind: str, ffn_kind: str,
             attn_cache = {"k": cache["k"], "v": cache["v"]}
         y, new_attn_cache = L.attention_apply(
             p["mixer"], h, cfg, positions=positions, cache=attn_cache,
-            cache_pos=cache_pos, causal=causal, page_state=page_state)
+            cache_pos=cache_pos, causal=causal, page_state=page_state,
+            attn=attn)
         new_cache = dict(cache) if cache is not None else None
         if new_attn_cache is not None and new_cache is not None:
             new_cache.update(new_attn_cache)
@@ -112,7 +120,8 @@ def block_apply(p, x, cfg, *, kind: str, ffn_kind: str,
 
     if ffn_kind != "none":
         h = _norm_apply(cfg, p["norm2"], x)
-        y, aux = _ffn_apply(p["ffn"], h, cfg, ffn_kind)
+        y, aux = _ffn_apply(p["ffn"], h, cfg, ffn_kind, page_state,
+                            expert_group)
         x = x + y
     x = constrain(x, ("batch", "seq", "embed"))
     return x, new_cache, aux
@@ -141,14 +150,18 @@ def cross_kv(p, enc_out, cfg):
 
 # ------------------------------------------------------------------ stack
 def period_pattern(cfg) -> tuple[list[str], list[str], int]:
-    """(mixer kinds, ffn kinds, period length)."""
+    """(mixer kinds, ffn kinds, period length).  The period also repeats
+    the layers' attention kinds (``cfg.layer_attn``), so each position
+    of it has one static window and RoPE."""
     kinds = cfg.layer_kinds()
     ffns = cfg.ffn_kinds()
+    attns = [cfg.layer_attn(i) for i in range(cfg.n_layers)]
     n = cfg.n_layers
     for p in range(1, n + 1):
         if n % p:
             continue
-        if (kinds == kinds[:p] * (n // p)) and (ffns == ffns[:p] * (n // p)):
+        if (kinds == kinds[:p] * (n // p)) and (ffns == ffns[:p] * (n // p)) \
+                and attns == attns[:p] * (n // p):
             return kinds[:p], ffns[:p], p
     return kinds, ffns, n
 
@@ -189,21 +202,32 @@ def stack_apply(params, x, cfg, *, positions=None, caches=None,
     closed over rather than scanned - all layers of one step read the
     same tables.
 
-    Returns (x, new_caches, aux_sum).
+    Returns (x, new_caches, aux_sum).  On the paged path an expert
+    layer's aux is its counters (``moe.moe_serve``), summed over layers.
     """
     kinds, ffns, period = period_pattern(cfg)
+    experts = None
+    if page_state is not None and "moe" in ffns and cfg.scan_layers:
+        # The expert kernels read each group's weights in place from
+        # the whole stack: a scanned slice would be copied out for them.
+        experts, params = _split_experts(params, ffns)
 
     def body(carry, scanned):
         x, aux_acc = carry
-        gp, gcache, genc = scanned
+        gp, gcache, genc = scanned[:3]
+        group = scanned[3] if experts is not None else None
         new_gcache = {} if gcache is not None else None
         for i in range(period):
             cache_i = gcache[f"l{i}"] if gcache is not None else None
             enc_i = genc[f"l{i}"] if genc is not None else None
+            gp_i = gp[f"l{i}"]
+            if experts is not None and f"l{i}" in experts:
+                gp_i = dict(gp_i, ffn=dict(gp_i["ffn"], **experts[f"l{i}"]))
             x, nc, aux = block_apply(
-                gp[f"l{i}"], x, cfg, kind=kinds[i], ffn_kind=ffns[i],
+                gp_i, x, cfg, kind=kinds[i], ffn_kind=ffns[i],
                 positions=positions, cache=cache_i, cache_pos=cache_pos,
-                enc_cache=enc_i, causal=causal, page_state=page_state)
+                enc_cache=enc_i, causal=causal, page_state=page_state,
+                attn=cfg.layer_attn(i), expert_group=group)
             if new_gcache is not None:
                 new_gcache[f"l{i}"] = nc
             for k, v in aux.items():
@@ -214,9 +238,14 @@ def stack_apply(params, x, cfg, *, positions=None, caches=None,
         body = jax.checkpoint(body, prevent_cse=False)
 
     init_aux = {"load_balance": jnp.float32(0.0), "router_z": jnp.float32(0.0)}
+    if page_state is not None and "moe" in ffns:
+        init_aux.update(moe_rows_local=jnp.int32(0),
+                        moe_experts_active=jnp.int32(0))
     if cfg.scan_layers:
-        (x, aux), new_caches = jax.lax.scan(
-            body, (x, init_aux), (params, caches, enc_caches))
+        xs = (params, caches, enc_caches)
+        if experts is not None:
+            xs += (jnp.arange(cfg.n_layers // period, dtype=jnp.int32),)
+        (x, aux), new_caches = jax.lax.scan(body, (x, init_aux), xs)
         return x, new_caches, aux
 
     # Unrolled execution (used by the dry-run cost probes: while-loop bodies
@@ -235,6 +264,18 @@ def stack_apply(params, x, cfg, *, positions=None, caches=None,
     if outs and outs[0] is not None:
         new_caches = jax.tree.map(lambda *a: jnp.stack(a), *outs)
     return x, new_caches, aux
+
+
+def _split_experts(params, ffns):
+    """(each expert layer's stacked wg/wu/wd by period position, the
+    params without them)."""
+    experts, rest = {}, dict(params)
+    for i, kind in enumerate(ffns):
+        if kind == "moe":
+            ffn = dict(rest[f"l{i}"]["ffn"])
+            experts[f"l{i}"] = {k: ffn.pop(k) for k in ("wg", "wu", "wd")}
+            rest[f"l{i}"] = dict(rest[f"l{i}"], ffn=ffn)
+    return experts, rest
 
 
 def stack_init_paged_cache(cfg, num_pages: int, page_size: int, dtype,

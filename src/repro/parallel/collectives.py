@@ -128,6 +128,7 @@ def shardmap_paged_attention(
     data_axis: str = "data",
     scale: float | None = None,
     codec=None,          # page codec (name or PageCodec); None/"fp" = raw
+    window: int | None = None,  # newest rows each query attends
 ):
     """Tensor-parallel paged attention: the cascaded ACC merge over a
     KV-head-sharded page pool.
@@ -229,7 +230,7 @@ def shardmap_paged_attention(
                 qg, pools["k_pages"], pools["v_pages"], pt_l, kv_lens,
                 impl=impl, scale=scale, codec=rcodec,
                 k_scales=pools.get("k_scale"),
-                v_scales=pools.get("v_scale"))
+                v_scales=pools.get("v_scale"), window=window)
         elif mode == "verify":
             pools = page_codec.encode_write(
                 paged_pf_k.write_chunk_kv, cod, pools, k_new, v_new, pt,
@@ -239,7 +240,7 @@ def shardmap_paged_attention(
                 qg, pools["k_pages"], pools["v_pages"], pt_l, la_l, lb_l,
                 impl=impl, scale=scale, codec=rcodec,
                 k_scales=pools.get("k_scale"),
-                v_scales=pools.get("v_scale"))
+                v_scales=pools.get("v_scale"), window=window)
         else:
             pools = page_codec.encode_write(
                 paged_pf_k.write_chunk_kv, cod, pools, k_new, v_new, pt,
@@ -251,7 +252,7 @@ def shardmap_paged_attention(
                 kv_lens,
                 impl=impl, scale=scale, codec=rcodec,
                 k_scales=pools.get("k_scale"),
-                v_scales=pools.get("v_scale"))
+                v_scales=pools.get("v_scale"), window=window)
 
         # Pad the local triplet to full head width with the neutral
         # element, so the gathered merge reconstitutes every head.

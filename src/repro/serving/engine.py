@@ -111,12 +111,13 @@ def _serving_jits(model, mesh=None, codec="fp"):
     # (n rows replicating the same logits under per-branch seeds) while
     # a plain request samples through the *identical* code path - the
     # bit-identity the parallel-sampling conformance suite pins down.
+    # Every step also returns the expert layers' counters (zeros for a
+    # model without experts), read with the step's tokens.
     def prefill_fn(params, layers, tokens, page_table, start_pos, last_pos):
-        logits, layers = model.paged_prefill(params, layers, tokens,
-                                             page_table, last_pos=last_pos,
-                                             start_pos=start_pos, mesh=mesh,
-                                             codec=codec)
-        return logits[:, 0], layers
+        logits, layers, moe = model.paged_prefill(
+            params, layers, tokens, page_table, last_pos=last_pos,
+            start_pos=start_pos, mesh=mesh, codec=codec, stats=True)
+        return logits[:, 0], layers, moe
 
     # Prompt-logprobs prefill: same KV writes, but the LM head runs at
     # every chunk position (the cost ``Request.logprobs`` opts into)
@@ -126,17 +127,16 @@ def _serving_jits(model, mesh=None, codec="fp"):
     # the valid prefix).
     def prefill_lp_fn(params, layers, tokens, page_table, start_pos,
                       last_pos, targets):
-        logits, layers = model.paged_prefill(params, layers, tokens,
-                                             page_table, last_pos=last_pos,
-                                             start_pos=start_pos, mesh=mesh,
-                                             codec=codec,
-                                             return_all_logits=True)
+        logits, layers, moe = model.paged_prefill(
+            params, layers, tokens, page_table, last_pos=last_pos,
+            start_pos=start_pos, mesh=mesh, codec=codec,
+            return_all_logits=True, stats=True)
         lsm = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         plp = jnp.take_along_axis(
             lsm, targets[..., None].astype(jnp.int32), axis=-1)[..., 0]
         last = jnp.take_along_axis(
             logits, last_pos[:, None, None].astype(jnp.int32), axis=1)
-        return last[:, 0], plp, layers
+        return last[:, 0], plp, layers, moe
 
     # ``greedy`` is a static (trace-time) flag: when every row this call
     # serves is argmax (temperature 0, no penalty), the whole sampling
@@ -187,9 +187,9 @@ def _serving_jits(model, mesh=None, codec="fp"):
                   beam_k, want_lp):
         # spec_k == 0 fast path: the single-token decode attention
         # (append + grouped decode) instead of the chunk-write verify.
-        logits, layers = model.paged_decode_step(
+        logits, layers, moe = model.paged_decode_step(
             params, layers, tokens, page_table, seq_lens, mesh=mesh,
-            codec=codec)
+            codec=codec, stats=True)
         if greedy:
             toks = jnp.argmax(logits[:, :1], axis=-1).astype(jnp.int32)
         else:
@@ -198,14 +198,14 @@ def _serving_jits(model, mesh=None, codec="fp"):
                 logits[:, 0], presence, seeds, pos, temp, top_k, top_p,
                 rep_pen)[:, None]
         tkv, tki, lp = _extras(logits, toks, beam_k, want_lp)
-        return toks, tkv, tki, lp, layers
+        return toks, tkv, tki, lp, moe, layers
 
     def verify_fn(params, layers, tokens, page_table, seq_lens, chunk_lens,
                   seeds, temp, top_k, top_p, rep_pen, presence, greedy,
                   beam_k, want_lp):
-        logits, layers = model.paged_verify_step(
+        logits, layers, moe = model.paged_verify_step(
             params, layers, tokens, page_table, seq_lens, chunk_lens,
-            mesh=mesh, codec=codec)
+            mesh=mesh, codec=codec, stats=True)
         b, kw, v = logits.shape
         if greedy:
             toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -220,7 +220,7 @@ def _serving_jits(model, mesh=None, codec="fp"):
                 rep(seeds), pos.reshape(-1), rep(temp), rep(top_k),
                 rep(top_p), rep(rep_pen)).reshape(b, kw)
         tkv, tki, lp = _extras(logits, toks, beam_k, want_lp)
-        return toks, tkv, tki, lp, layers
+        return toks, tkv, tki, lp, moe, layers
 
     def copy_fn(layers, src, dst):
         # Layer pools are stacked (groups, P, Hkv, page, d): page axis 1.
@@ -394,6 +394,15 @@ class ServingEngine:
                       # width it would walk page by page, summed over
                       # decode steps without drafts:
                       "decode_kv_pages": 0, "decode_kv_table_pages": 0,
+                      # ... and of those the pages in each slot's window,
+                      # what a windowed layer's kernel walks:
+                      "decode_kv_window_pages": 0,
+                      # Expert layers, summed over layers: (token,
+                      # choice) rows the held experts computed, all rows
+                      # routed (tokens x top-k), and (layer, held expert)
+                      # pairs with a row, summed over calls:
+                      "moe_rows_local": 0, "moe_rows_routed": 0,
+                      "moe_experts_active": 0,
                       "draft_tokens": 0, "draft_accepted": 0,
                       # Draft-quality EMA (alpha 0.2 over verify steps
                       # that proposed >= 1 draft) and the per-step draft
@@ -415,6 +424,13 @@ class ServingEngine:
         # Wall, thread-CPU seconds and passes of every step and frontend
         # phase (repro.serving.tracing), beside the counters above.
         self.phases = tracing.new_totals()
+        cfg = model.cfg
+        self._moe_layers = sum(k == "moe" for k in cfg.ffn_kinds())
+        windows = {cfg.layer_attn(i).window for i in range(cfg.n_layers)}
+        windows.discard(None)
+        self._window = max(windows, default=None)
+        # Expert counters of prefill calls, read at the next sync.
+        self._moe_pending: list = []
         (self._prefill, self._decode, self._verify, self._copy,
          self._sample, self._topk,
          self._prefill_lp) = _serving_jits(model, mesh, self.kv_codec)
@@ -460,7 +476,7 @@ class ServingEngine:
             width = self._pow2_width(self.cache.pages_for(n))
             toks = np.zeros((1, lpad), np.int32)
             toks[0, :n] = prompt
-            logits, self.layers = self._prefill(
+            logits, self.layers, _ = self._prefill(
                 self.params, self.layers, jnp.asarray(toks),
                 jnp.asarray(self.cache.page_table[slot:slot + 1, :width]),
                 jnp.zeros((1,), jnp.int32), jnp.asarray([n - 1], jnp.int32))
@@ -499,6 +515,27 @@ class ServingEngine:
         per_shard = self.tp * batch * rows * cfg.n_heads * \
             (cfg.d_head + 2) * 4 * cfg.n_layers
         self.stats["triplet_bytes"] += self.tp * per_shard
+
+    def _fetch(self, *arrays):
+        """The step's outputs on the host, the expert counters of the
+        calls since the last read in the same transfer (a model without
+        experts reads only ``arrays``)."""
+        if not self._moe_layers:
+            return tuple(np.asarray(a) for a in arrays)
+        got = jax.device_get((arrays, self._moe_pending))
+        self._moe_pending = []
+        for rows, active in got[1]:
+            self.stats["moe_rows_local"] += int(rows)
+            self.stats["moe_experts_active"] += int(active)
+        return got[0]
+
+    def _route_count(self, tokens: int, moe) -> None:
+        """Account a call's expert rows: all routed now, the held
+        experts' (device counters) at the next read."""
+        if self._moe_layers:
+            self.stats["moe_rows_routed"] += \
+                tokens * self.model.cfg.moe_top_k * self._moe_layers
+            self._moe_pending.append(moe)
 
     # ------------------------------------------------------------- intake
     def submit(self, req: Request) -> None:
@@ -706,14 +743,15 @@ class ServingEngine:
                     args += (jnp.asarray(self._prompt_targets(grp, lpad)),)
             with self._phase("engine.prefill.dispatch"):
                 if want_plp:
-                    logits, plp, self.layers = self._prefill_lp(
+                    logits, plp, self.layers, moe = self._prefill_lp(
                         self.params, self.layers, *args)
                 else:
-                    logits, self.layers = self._prefill(
+                    logits, self.layers, moe = self._prefill(
                         self.params, self.layers, *args)
+            self._route_count(sum(len(ck.tokens) for ck in grp), moe)
             if want_plp:
                 with self._phase("engine.prefill.sync"):
-                    plp = np.asarray(plp)
+                    plp, = self._fetch(plp)
             with self._phase("engine.prefill.commit"):
                 if want_plp:
                     self._record_prompt_lps(grp, plp)
@@ -826,8 +864,7 @@ class ServingEngine:
             lrows = jnp.take(logits, args[0], axis=0)
             toks, lps = self._sample(lrows, *args[1:], greedy, want_lp)
         with self._phase("engine.prefill.sync"):
-            toks = np.asarray(toks)
-            lps = np.asarray(lps)
+            toks, lps = self._fetch(toks, lps)
         with self._phase("engine.prefill.commit"):
             for j, (i, slot) in enumerate(rows):
                 tok = int(toks[j])
@@ -924,11 +961,11 @@ class ServingEngine:
                     jnp.asarray(self._rep_pen),
                     jnp.asarray(_NO_PRESENCE if greedy else self._presence))
         with self._phase("engine.decode.dispatch"):
-            sampled, tkv, tki, lps, self.layers = step_fn(
+            sampled, tkv, tki, lps, moe, self.layers = step_fn(
                 self.params, self.layers, *args, greedy, beam_k, want_lp)
+        self._route_count(int(cl.sum()), moe)
         with self._phase("engine.decode.sync"):
-            sampled = np.asarray(sampled)
-            lps = np.asarray(lps)
+            sampled, lps = self._fetch(sampled, lps)
         with self._phase("engine.decode.commit"):
             self.stats["decode_steps"] += 1
             self.stats["decode_slot_steps"] += len(steps)
@@ -937,11 +974,22 @@ class ServingEngine:
                     self.cache.pages_for(int(dl[s.slot] + cl[s.slot]))
                     for s in steps)
                 self.stats["decode_kv_table_pages"] += self.max_batch * width
+                if self._window is not None:
+                    self.stats["decode_kv_window_pages"] += sum(
+                        self._window_pages(int(dl[s.slot] + cl[s.slot]))
+                        for s in steps)
             self._count_triplets(self.max_batch, kw)
             self._commit_decode(steps, sampled, lps, finished)
             if beam_groups:
                 self._reorder_beams(beam_groups, np.asarray(tkv),
                                     np.asarray(tki), finished)
+
+    def _window_pages(self, kv_len: int) -> int:
+        """Pages a windowed layer's decode walks for a slot of
+        ``kv_len`` rows: from the page of the window's first row to the
+        last."""
+        first = max(kv_len - self._window, 0) // self.page_size
+        return (kv_len - 1) // self.page_size - first + 1
 
     def _plan_decode(self):
         """This step's decode work: the scheduler's decode steps (at

@@ -682,3 +682,184 @@ def test_paged_codec_cow_fork_and_rollback(name, impl):
     d_j = np.asarray(ops.paged_decode_attention(q[:, :1], kdx, vdx, mat,
                                                 sl, **ck2))
     np.testing.assert_array_equal(d_j, d_m)
+
+
+# ------------------------------------------------- windowed paged kernels
+# Digests of the paged kernels' and ops' outputs without a window, on
+# fixed inputs, recorded from the kernels before windows were added: the
+# no-window path must stay bit-identical.
+NO_WINDOW_DIGESTS = {
+    "decode-False": "a4002a4ae281e37bc266b2fabe5c9285",
+    "prefill-False": "c8d7042e26853b33318037b0f1430db3",
+    "verify-False": "e9c771e1160271cf964c332d4b5f3cdd",
+    "ops-False": "3f41622ccfb272908d480a80d0b8c3c1",
+    "decode-True": "ecf0fad960ae9fb16f7dfb9d4dfecd5d",
+    "prefill-True": "bb4975f6a92598ca2ec4862767bdf930",
+    "verify-True": "b996684ae9c1e1d9bc43bc73a45aada4",
+    "ops-True": "ffceb6172070c7cfb7d3d878791c8db2"}
+
+
+def _digest(arrays):
+    import hashlib
+    h = hashlib.sha256()
+    for a in arrays:
+        a = np.asarray(a)
+        h.update(f"{a.dtype}|{a.shape}|".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()[:32]
+
+
+@pytest.mark.parametrize("use_hfa", [False, True])
+def test_paged_no_window_bit_identical_to_before_windows(use_hfa):
+    from repro.kernels import paged_decode as pd
+    from repro.kernels import paged_prefill as pp
+    from repro.kernels import paged_verify as pv
+    rng = np.random.default_rng(17)
+    P, HKV, PAGE, D, B, G, W = 40, 2, 8, 32, 3, 4, 8
+    kp = jnp.asarray(rng.standard_normal((P, HKV, PAGE, D)), jnp.bfloat16)
+    vp = jnp.asarray(rng.standard_normal((P, HKV, PAGE, D)), jnp.bfloat16)
+    pt = jnp.asarray(rng.permutation(P)[:B * W].reshape(B, W), jnp.int32)
+    q = jnp.asarray(rng.standard_normal((B, HKV, G, D)), jnp.bfloat16)
+    kl = jnp.asarray([61, 0, 23], jnp.int32)
+    qc = jnp.asarray(rng.standard_normal((B, HKV, G, 16, D)), jnp.bfloat16)
+    sp = jnp.asarray([30, 0, 9], jnp.int32)
+    qv = jnp.asarray(rng.standard_normal((B, HKV, G, 3, D)), jnp.bfloat16)
+    cl = jnp.asarray([3, 0, 2], jnp.int32)
+    sl = jnp.asarray([50, 0, 12], jnp.int32)
+    got = {
+        "decode": pd.paged_decode_partial_pallas(
+            q, kp, vp, pt, kl, interpret=True, use_hfa=use_hfa),
+        "prefill": pp.paged_prefill_partial_pallas(
+            qc, kp, vp, pt, sp, sp + 16, interpret=True, use_hfa=use_hfa),
+        "verify": pv.paged_verify_partial_pallas(
+            qv, kp, vp, pt, sl, cl, interpret=True, use_hfa=use_hfa)}
+    impl = "hfa_pallas" if use_hfa else "fa2"
+    got["ops"] = [
+        ops.paged_decode_attention(q.reshape(B, 1, HKV * G, D), kp, vp, pt,
+                                   kl, impl=impl),
+        ops.paged_prefill_attention(
+            jnp.swapaxes(qc.reshape(B, HKV * G, 16, D), 1, 2), kp, vp, pt,
+            sp, jnp.asarray([16, 5, 16], jnp.int32), impl=impl),
+        ops.paged_verify_attention(
+            jnp.swapaxes(qv.reshape(B, HKV * G, 3, D), 1, 2), kp, vp, pt,
+            sl, cl, impl=impl)]
+    for name, arrays in got.items():
+        assert _digest(arrays) == NO_WINDOW_DIGESTS[f"{name}-{use_hfa}"], \
+            name
+
+
+def _window_setup(seed, *, b=3, hkv=2, g=4, d=32, page=8, width=12):
+    """f32 pools, a shuffled table, contexts of several pages (one of
+    them mid-page), a free slot; the pages no window reaches hold NaN
+    (``poison``), so a kernel that reads one shows it."""
+    rng = np.random.default_rng(seed)
+    num_pages = b * width + 1
+    kp = np.asarray(_rand((num_pages, hkv, page, d), jnp.float32, seed + 1))
+    vp = np.asarray(_rand((num_pages, hkv, page, d), jnp.float32, seed + 2))
+    pt = rng.permutation(num_pages)[:b * width].reshape(b, width)
+    return kp, vp, pt.astype(np.int32), rng
+
+
+def _poison(kp, vp, pt, lo_rows, page):
+    """NaN into every page wholly before each slot's first attended row."""
+    kp, vp = kp.copy(), vp.copy()
+    for row, lo in zip(pt, lo_rows):
+        for j in range(lo // page):
+            kp[row[j]] = np.nan
+            vp[row[j]] = np.nan
+    return jnp.asarray(kp), jnp.asarray(vp)
+
+
+# Windows that cut mid-page, a page multiple, and one at least the
+# longest context (it then changes nothing).
+WINDOWS = [13, 16, 96]
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("use_hfa", [False, True])
+def test_paged_decode_window_matches_oracle(window, use_hfa):
+    """Windowed decode: the kernel (poisoned pages before each window
+    never read) equals the jnp gather oracle over the clean pools; a
+    window at least the context equals the kernel without one."""
+    page = 8
+    kp, vp, pt, _ = _window_setup(700)
+    lens = np.array([61, 0, 90], np.int32)
+    q = _rand((3, 2, 4, 32), jnp.float32, 703)
+    kpp, vpp = _poison(kp, vp, pt, np.maximum(lens - window, 0), page)
+    impl = "hfa_pallas" if use_hfa else "fa2"
+    q4 = q.reshape(3, 1, 8, 32)
+    pal = np.asarray(ops.paged_decode_attention(
+        q4, kpp, vpp, jnp.asarray(pt), jnp.asarray(lens), impl=impl,
+        force_pallas=True, window=window))
+    ref = np.asarray(ops.paged_decode_attention(
+        q4, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
+        jnp.asarray(lens), impl=impl, window=window))
+    assert np.isfinite(pal).all()
+    np.testing.assert_allclose(pal, ref, atol=2e-2 if use_hfa else 1e-5)
+    if window >= lens.max():
+        full = np.asarray(ops.paged_decode_attention(
+            q4, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
+            jnp.asarray(lens), impl=impl, force_pallas=True))
+        np.testing.assert_array_equal(pal, full)
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("use_hfa", [False, True])
+def test_paged_prefill_and_verify_window_match_oracle(window, use_hfa):
+    """Windowed chunked prefill and speculative verify: each kernel, with
+    the pages no window of the chunk reaches poisoned, equals the jnp
+    gather oracle over the clean pools."""
+    page = 8
+    kp, vp, pt, _ = _window_setup(710)
+    impl = "hfa_pallas" if use_hfa else "fa2"
+    tol = 2e-2 if use_hfa else 1e-5
+    # Prefill: chunks of up to 16 rows starting mid-context.
+    sp = np.array([40, 0, 67], np.int32)
+    cl = np.array([16, 5, 11], np.int32)
+    q = _rand((3, 16, 8, 32), jnp.float32, 713)
+    kpp, vpp = _poison(kp, vp, pt, np.maximum(sp - window + 1, 0), page)
+    pal = np.asarray(ops.paged_prefill_attention(
+        q, kpp, vpp, jnp.asarray(pt), jnp.asarray(sp), jnp.asarray(cl),
+        impl=impl, force_pallas=True, window=window))
+    ref = np.asarray(ops.paged_prefill_attention(
+        q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
+        jnp.asarray(sp), jnp.asarray(cl), impl=impl, window=window))
+    for b, n in enumerate(cl):
+        assert np.isfinite(pal[b, :n]).all()
+        np.testing.assert_allclose(pal[b, :n], ref[b, :n], atol=tol)
+    # Verify: K = 3 rows after each context.
+    sl = np.array([58, 0, 85], np.int32)
+    vcl = np.array([3, 0, 2], np.int32)
+    qv = _rand((3, 3, 8, 32), jnp.float32, 714)
+    kpp, vpp = _poison(kp, vp, pt, np.maximum(sl - window + 1, 0), page)
+    pal = np.asarray(ops.paged_verify_attention(
+        qv, kpp, vpp, jnp.asarray(pt), jnp.asarray(sl), jnp.asarray(vcl),
+        impl=impl, force_pallas=True, window=window))
+    ref = np.asarray(ops.paged_verify_attention(
+        qv, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
+        jnp.asarray(sl), jnp.asarray(vcl), impl=impl, window=window))
+    for b, n in enumerate(vcl):
+        assert np.isfinite(pal[b, :n]).all()
+        np.testing.assert_allclose(pal[b, :n], ref[b, :n], atol=tol)
+
+
+@pytest.mark.parametrize("window", WINDOWS)
+@pytest.mark.parametrize("use_hfa", [False, True])
+def test_paged_verify_k1_bit_equal_decode_with_window(window, use_hfa):
+    """With a window, verify at K = 1 is the decode kernel bit for bit
+    (speculation stays token-exact on windowed layers)."""
+    from repro.kernels import paged_decode as pd
+    from repro.kernels import paged_verify as pv
+    kp, vp, pt, _ = _window_setup(720, width=24)
+    kp, vp, pt = jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt)
+    sl = jnp.asarray([60, 0, 190], jnp.int32)
+    q = _rand((3, 2, 4, 32), jnp.float32, 723)
+    o, m, l = pd.paged_decode_partial_pallas(
+        q, kp, vp, pt, jnp.where(sl > 0, sl + 1, 0), interpret=True,
+        use_hfa=use_hfa, window=window)
+    ov, mv, lv = pv.paged_verify_partial_pallas(
+        q[:, :, :, None], kp, vp, pt, sl, (sl > 0).astype(jnp.int32),
+        interpret=True, use_hfa=use_hfa, window=window)
+    np.testing.assert_array_equal(np.asarray(o), np.asarray(ov[..., 0, :]))
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(mv[..., 0]))
+    np.testing.assert_array_equal(np.asarray(l), np.asarray(lv[..., 0]))

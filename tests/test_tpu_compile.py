@@ -139,3 +139,77 @@ def test_supported_page_sizes_compile(one_chip, page):
             q, k, v, pt, kl, interpret=False, use_hfa=True),
         q, pool, pool, _i32(one_chip, B, 256 // page), _i32(one_chip, B))
     assert "tpu_custom_call" in text
+
+
+# Mellum2-12B-A2.5B at published widths: 4 KV heads of 128, GQA group 8,
+# windowed layers of 1024 rows, 32 decode slots, its benchmark pool; the
+# held experts' SwiGLU matrices (d 2304, expert width 896).
+MELLUM = dict(b=32, hkv=4, g=8, width=375, num_pages=4100, window=1024)
+
+
+@pytest.mark.parametrize("use_hfa", [False, True])
+def test_paged_decode_window_compiles(one_chip, use_hfa):
+    """The windowed decode kernel is its own call,
+    paged_flash_decode_window."""
+    c = MELLUM
+    pool, _, _ = _pools(one_chip, "fp", c["hkv"], num_pages=c["num_pages"])
+    q = jax.ShapeDtypeStruct((c["b"], c["hkv"], c["g"], D), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compile_text(
+        lambda q, k, v, pt, kl: paged_decode.paged_decode_partial_pallas(
+            q, k, v, pt, kl, interpret=False, use_hfa=use_hfa,
+            window=c["window"]),
+        q, pool, pool, _i32(one_chip, c["b"], c["width"]),
+        _i32(one_chip, c["b"]))
+    assert "paged_flash_decode_window" in text
+    assert "paged_flash_decode_partial" not in text
+
+
+@pytest.mark.parametrize("chunk", [128, 512])
+def test_paged_prefill_and_verify_window_compile(one_chip, chunk):
+    """Windowed prefill at group 8: a 512-token chunk (4096 query rows)
+    runs as query blocks that fit VMEM; verify of K = 5."""
+    c = MELLUM
+    pool, _, _ = _pools(one_chip, "fp", c["hkv"], num_pages=c["num_pages"])
+    q = jax.ShapeDtypeStruct((1, c["hkv"], c["g"], chunk, D), jnp.bfloat16,
+                             sharding=one_chip)
+    text = _compile_text(
+        lambda q, k, v, pt, sp, kl: paged_prefill.paged_prefill_partial_pallas(
+            q, k, v, pt, sp, kl, interpret=False, use_hfa=True,
+            window=c["window"]),
+        q, pool, pool, _i32(one_chip, 1, c["width"]), _i32(one_chip, 1),
+        _i32(one_chip, 1))
+    assert "paged_prefill_partial" in text
+    qv = jax.ShapeDtypeStruct((c["b"], c["hkv"], c["g"], 5, D),
+                              jnp.bfloat16, sharding=one_chip)
+    text = _compile_text(
+        lambda q, k, v, pt, sl, cl: paged_verify.paged_verify_partial_pallas(
+            q, k, v, pt, sl, cl, interpret=False, use_hfa=True,
+            window=c["window"]),
+        qv, pool, pool, _i32(one_chip, c["b"], c["width"]),
+        _i32(one_chip, c["b"]), _i32(one_chip, c["b"]))
+    assert "paged_verify_partial" in text
+
+
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_moe_gmm_compiles(one_chip, tokens):
+    """The held experts' grouped matmuls, named moe_gmm: a decode step's
+    32 tokens and a 512-token prefill chunk, top-8 of 64 router outputs
+    onto 16 held experts, the weights read in place from the stack of 7
+    layer groups."""
+    from repro.kernels import grouped_matmul as gm
+    d, ff, held = 2304, 896, 16
+    tm = gm.tile_rows(tokens * 8, 64)
+    m = gm.num_tiles(tokens * 8, held, tm) * tm
+    te = _i32(one_chip, m // tm)
+    nl = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def bf16(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    for k, n, out in ((d, ff, jnp.bfloat16), (ff, d, jnp.float32)):
+        text = _compile_text(
+            lambda x, w, te, nl, g, out=out: gm.gmm_pallas(
+                x, w, te, nl, tm=tm, out_dtype=out, interpret=False,
+                group=g),
+            bf16(m, k), bf16(7, held, k, n), te, nl, nl)
+        assert "moe_gmm" in text
